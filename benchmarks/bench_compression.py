@@ -37,7 +37,7 @@ from repro.fl import ManagementService, TaskConfig
 from repro.fl.task import CompressionConfig, SelectionCriteria
 
 _CRIT = SelectionCriteria(require_attestation=False)
-_WHISPER_LORA = lora.LoRAConfig(rank=2, alpha=4.0, include=("attn",))
+WHISPER_LORA = lora.LoRAConfig(rank=2, alpha=4.0, include=("attn",))
 
 
 def bench_whisper_fraction(rows) -> float:
@@ -49,7 +49,7 @@ def bench_whisper_fraction(rows) -> float:
 
     params = abstract_params(get_config("whisper-medium"))
     dense = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
-    frac = lora.upload_fraction(_WHISPER_LORA, params)
+    frac = lora.upload_fraction(WHISPER_LORA, params)
     print(f"#   whisper-medium: {dense / 1e6:.0f}M params "
           f"({dense * 4 / 1e9:.2f} GB f32), rank-2 attn LoRA upload "
           f"fraction {frac * 100:.3f}%")
@@ -154,9 +154,9 @@ def bench_whisper_lora_e2e(rows):
     dense_bytes = lora.n_params(base) * 4
     print(f"#   whisper-medium materialized: {dense_bytes / 1e9:.2f} GB "
           f"in {time.perf_counter() - t0:.1f}s")
-    adapters0 = lora.init_adapters(_WHISPER_LORA, base,
+    adapters0 = lora.init_adapters(WHISPER_LORA, base,
                                    jax.random.PRNGKey(1))
-    spec = lora.lora_spec(_WHISPER_LORA, base,
+    spec = lora.lora_spec(WHISPER_LORA, base,
                           lambda p, b: loss_fn(cfg, p, b),
                           sgd(1e-3), local_steps=1)
     local_update = make_local_update(spec)
@@ -178,7 +178,8 @@ def bench_whisper_lora_e2e(rows):
     t0 = time.perf_counter()
     deltas, losses = [], []
     for i in range(4):      # serial: one 3 GB merge live at a time
-        delta, loss = local_update(adapters0, client_batch(100 + i))
+        delta, loss = local_update(adapters0, client_batch(100 + i),
+                                   spec.frozen)
         deltas.append(jax.tree.map(np.asarray, delta))
         losses.append(float(loss))
     train_s = time.perf_counter() - t0

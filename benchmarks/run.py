@@ -25,6 +25,7 @@ from benchmarks import (bench_async, bench_cohort, bench_compression,
                         bench_fleet, bench_kernels, bench_scaling,
                         bench_secureagg, bench_spam, bench_trace)
 from benchmarks.common import write_bench_json
+from repro import compile_cache
 
 SUITES = [
     ("fig11_left", bench_spam),
@@ -44,6 +45,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    compile_cache.configure()
     only = set(args.only.split(",")) if args.only else None
 
     print("name,us_per_call,derived")

@@ -1,17 +1,14 @@
-"""JAX version compatibility shims.
+"""Thin wrappers over JAX's mesh APIs, written for JAX 0.9.
 
-Supported range: JAX 0.4.x – 0.5.x. The repo pins 0.4.37 in the container,
-but the mesh-introspection helpers below are written against the 0.5 API so
-an upgrade is a no-op.
+The call sites (models/model.py, models/attention.py, models/moe.py,
+launch/fl_step.py, launch/mesh.py, core/cohort_engine.py) go through these
+functions so each mesh question has one answer in one place.
 
-``get_abstract_mesh`` is the load-bearing shim: the §Perf
-with-sharding-constraint helpers (models/model.py, models/attention.py,
-models/moe.py, launch/fl_step.py) ask "is a mesh ambient, and which axes
-does it have?" before pinning activation layouts. On JAX >= 0.5 that is
-``jax.sharding.get_abstract_mesh()``; on 0.4.x the equivalent ambient-mesh
-state for a ``with mesh:`` context lives at
-``jax.interpreters.pxla.thread_resources.env.physical_mesh``. Both are
-normalized to *None when unmeshed* so call sites stay a plain
+``get_abstract_mesh`` is the load-bearing one: the §Perf
+with-sharding-constraint helpers ask "is a mesh ambient, and which axes
+does it have?" before pinning activation layouts. JAX answers with an
+``AbstractMesh`` whose ``axis_names`` is empty when no mesh is set; this
+module normalizes that to *None when unmeshed*, so call sites stay a plain
 ``if mesh is None: return x`` no-op on CPU smoke tests.
 """
 from __future__ import annotations
@@ -20,28 +17,11 @@ import jax
 
 
 def get_abstract_mesh():
-    """Return the ambient (abstract or physical) mesh, or None if unmeshed.
-
-    The returned object — when not None — has ``axis_names`` and
-    ``axis_sizes`` attributes on every supported JAX version; use
-    :func:`mesh_axis_sizes` for a name->size dict.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        try:
-            mesh = get()
-        except Exception:
-            mesh = None
-        if mesh is not None and getattr(mesh, "axis_names", ()):
-            return mesh
-        # fall through: 0.5's AbstractMesh() sentinel for "no mesh" has no
-        # axes; a ``with mesh:`` context may still be visible below.
-    try:
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-    except Exception:
-        return None
-    if mesh is None or getattr(mesh, "empty", True):
+    """Return the ambient abstract mesh (set by :func:`set_mesh`), or None
+    if unmeshed. The returned object has ``axis_names`` and ``axis_sizes``;
+    use :func:`mesh_axis_sizes` for a name->size dict."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or not mesh.axis_names:
         return None
     return mesh
 
@@ -54,48 +34,23 @@ def mesh_axis_sizes(mesh) -> dict:
 
 
 def set_mesh(mesh):
-    """Context manager making ``mesh`` ambient: ``jax.set_mesh`` on >= 0.5,
-    the mesh's own ``with mesh:`` context (physical_mesh) on 0.4.x. Either
-    way :func:`get_abstract_mesh` sees it inside the block."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
+    """Context manager making ``mesh`` ambient (``jax.set_mesh``);
+    :func:`get_abstract_mesh` sees it inside the block."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-    """Version-portable ``shard_map``: ``jax.shard_map`` where it exists
-    (newer releases), else ``jax.experimental.shard_map.shard_map`` (the
-    0.4.x–0.5.x home). ``check_rep`` defaults to False because the FL
-    combine paths feed uint32 collectives (psum of limb states) whose
-    replication rule the checker rejects on some versions."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_rep)
-    except TypeError:
-        pass
-    try:
-        # newer API renamed the flag (check_vma on 0.7+); keep the checker
-        # OFF there too — dropping the flag would silently re-enable it
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_rep)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """``jax.shard_map`` with the replication checker (``check_vma``) off
+    by default: the FL combine paths feed uint32 collectives (psum of limb
+    states) whose replication rule the checker rejects."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the API supports them
-    (>= 0.5); 0.4.x meshes are implicitly Auto, so omitting is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` (GSPMD decides
+    layouts the sharding constraints leave open)."""
     kw = {} if devices is None else {"devices": devices}
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(axis_type.Auto,) * len(axis_names), **kw)
-        except TypeError:
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names), **kw)

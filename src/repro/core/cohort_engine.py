@@ -33,7 +33,7 @@ reference the parity tests check the vectorized paths against.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Callable
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -54,26 +54,41 @@ class LocalTrainSpec:
     ``local_steps`` steps on batches of identical shape (vectorization
     requires uniform local work — ragged cohorts pad or fall back to the
     serial path).
+
+    ``frozen``: an optional pytree every client reads but never trains
+    (LoRA's base model, ``repro.core.lora.lora_spec``); the loss is then
+    ``loss_fn(params, batch, frozen)``. Every execution path passes it to
+    its compiled call as an ARGUMENT, broadcast over clients: a closed-over
+    array would be embedded in the program as a constant, which for a
+    multi-GB base neither compiles in reasonable time nor fits a program.
     """
     loss_fn: Callable
     optimizer: Optimizer
     local_steps: int = 1
+    frozen: Any = None
 
 
 def make_local_update(spec: LocalTrainSpec) -> Callable:
-    """-> local_update(params, client_batches) -> (delta, mean_loss).
+    """-> local_update(params, client_batches, frozen=None)
+    -> (delta, mean_loss).
 
-    client_batches: pytree with leaves (local_steps, B, ...). The returned
+    client_batches: pytree with leaves (local_steps, B, ...); ``frozen`` is
+    ``spec.frozen`` (callers pass it, so it stays an argument). The returned
     delta (new - start params, f32) is the client's pseudo-gradient payload
     in the paper's convention (strategies add it; ``launch/fl_step.py``
     negates it where a server *gradient* is expected).
     """
     opt = spec.optimizer
 
-    def local_update(params, client_batches):
+    def local_update(params, client_batches, frozen=None):
+        def loss_fn(p, batch):
+            if frozen is None:
+                return spec.loss_fn(p, batch)
+            return spec.loss_fn(p, batch, frozen)
+
         def body(carry, batch):
             p, s = carry
-            loss, g = jax.value_and_grad(spec.loss_fn)(p, batch)
+            loss, g = jax.value_and_grad(loss_fn)(p, batch)
             upd, s = opt.update(g, s, p)
             return (apply_updates(p, upd), s), loss
 
@@ -103,7 +118,7 @@ def serial_cohort(spec: LocalTrainSpec) -> Callable:
             p_j = jax.tree.map(lambda a: a[j], params) if personalized \
                 else params
             b_j = jax.tree.map(lambda a: a[j], stacked_batches)
-            d, l = one(p_j, b_j)
+            d, l = one(p_j, b_j, spec.frozen)
             deltas.append(d)
             losses.append(l)
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *deltas)
@@ -116,11 +131,18 @@ def vmap_cohort(spec: LocalTrainSpec, *, personalized: bool = False
                 ) -> Callable:
     """One compiled vmap-over-clients call.
 
-    -> f(params, stacked_batches) -> (stacked_deltas, losses (n,)).
-    personalized=True: params leaves carry a leading (n_clients,) axis.
+    -> f(params, stacked_batches, frozen=None) -> (stacked_deltas,
+    losses (n,)); pass ``spec.frozen`` as ``frozen`` (broadcast, never
+    stacked). personalized=True: params leaves carry a leading
+    (n_clients,) axis.
     """
-    f = make_local_update(spec)
-    return jax.jit(jax.vmap(f, in_axes=(0 if personalized else None, 0)))
+    f = jax.vmap(make_local_update(spec),
+                 in_axes=(0 if personalized else None, 0, None))
+
+    def run(params, stacked_batches, frozen=None):
+        return f(params, stacked_batches, frozen)
+
+    return jax.jit(run)
 
 
 def shard_cohort(spec: LocalTrainSpec, mesh, *, axis: str = "data",
@@ -130,13 +152,18 @@ def shard_cohort(spec: LocalTrainSpec, mesh, *, axis: str = "data",
     Each device traces a vmap over its n/axis_size local clients; params
     are replicated (or client-sharded when personalized). n_clients must
     divide the axis size. On a 1-device mesh this is exactly vmap_cohort.
+    Same call signature as :func:`vmap_cohort`; ``frozen`` is replicated.
     """
     f = jax.vmap(make_local_update(spec),
-                 in_axes=(0 if personalized else None, 0))
-    in_specs = (P(axis) if personalized else P(), P(axis))
+                 in_axes=(0 if personalized else None, 0, None))
+    in_specs = (P(axis) if personalized else P(), P(axis), P())
     sharded = compat.shard_map(f, mesh=mesh, in_specs=in_specs,
                                out_specs=(P(axis), P(axis)))
-    return jax.jit(sharded)
+
+    def run(params, stacked_batches, frozen=None):
+        return sharded(params, stacked_batches, frozen)
+
+    return jax.jit(run)
 
 
 def stack_trees(trees: list):
@@ -225,7 +252,8 @@ class CohortEngine:
                                    for cid in client_ids])
             if self.mesh is not None:
                 self._check_divisible(len(client_ids))
-            deltas, losses = self._cohort_fn(False)(params, batches)
+            deltas, losses = self._cohort_fn(False)(params, batches,
+                                                    self.spec.frozen)
             return deltas, losses, self._n_samples(batches, stacked=True)
 
     def _run_waves(self, params, client_ids, round_idx: int, w: int):
@@ -248,7 +276,7 @@ class CohortEngine:
                               n_real=n_real):
                 batches = stack_trees([self.batch_fn(cid, round_idx)
                                        for cid in chunk])
-                deltas, losses = fn(params, batches)
+                deltas, losses = fn(params, batches, self.spec.frozen)
                 if n_samples is None:
                     n_samples = self._n_samples(batches, stacked=True)
                 host = jax.tree.map(np.asarray, deltas)
@@ -285,7 +313,8 @@ class CohortEngine:
                                                      round_idxs)])
             if self.mesh is not None:
                 self._check_divisible(len(client_ids))
-            deltas, losses = self._cohort_fn(True)(stacked_params, batches)
+            deltas, losses = self._cohort_fn(True)(stacked_params, batches,
+                                                   self.spec.frozen)
             return deltas, losses, self._n_samples(batches, stacked=True)
 
     # -- adapters ----------------------------------------------------------
@@ -298,7 +327,7 @@ class CohortEngine:
         def trainer(blob, round_idx):
             params = deserialize_pytree(blob, like=self.template)
             b = jax.tree.map(jnp.asarray, self.batch_fn(client_id, round_idx))
-            delta, loss = self._local(params, b)
+            delta, loss = self._local(params, b, self.spec.frozen)
             n = self._n_samples(b, stacked=False)
             return (jax.tree.map(lambda a: np.asarray(a, np.float32), delta),
                     n, {"loss": float(loss)})

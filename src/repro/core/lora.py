@@ -11,10 +11,10 @@ factor per layer). Federated tuning then becomes:
 
   - the FROZEN BASE is broadcast once (it never changes — clients cache
     it; the task's "model" is the ADAPTERS pytree only);
-  - each client trains only its adapters (``lora_spec`` closes the task's
-    loss over the frozen base, so ``CohortEngine`` and every execution
-    path — serial / vmap / shard_map / waves — run UNCHANGED on the small
-    adapter pytree);
+  - each client trains only its adapters (``lora_spec`` passes the frozen
+    base to the task's loss as an argument, so ``CohortEngine`` and every
+    execution path — serial / vmap / shard_map / waves — run UNCHANGED on
+    the small adapter pytree);
   - the flat vector entering ``privacy_engine.aggregate_stacked`` is the
     concatenated adapter delta, so DP clip/noise, quantize, pairwise
     masks, VG sums, limb combine, dropout recovery and streaming waves
@@ -137,16 +137,17 @@ def merge(cfg: LoRAConfig, base_params, adapters):
 def lora_spec(cfg: LoRAConfig, base_params, loss_fn, optimizer,
               local_steps: int = 1):
     """``LocalTrainSpec`` whose trainable params ARE the adapters pytree:
-    the loss closes over the frozen base and merges per call, so
+    the frozen base rides as the spec's ``frozen`` argument (passed to the
+    compiled call, broadcast over clients) and merges per call, so
     ``CohortEngine`` (and the whole sync/async/churn machinery behind it)
     runs verbatim on the small adapter tree."""
     from repro.core.cohort_engine import LocalTrainSpec
 
-    def adapter_loss(adapters, batch):
-        return loss_fn(merge(cfg, base_params, adapters), batch)
+    def adapter_loss(adapters, batch, base):
+        return loss_fn(merge(cfg, base, adapters), batch)
 
     return LocalTrainSpec(loss_fn=adapter_loss, optimizer=optimizer,
-                          local_steps=local_steps)
+                          local_steps=local_steps, frozen=base_params)
 
 
 def n_params(tree) -> int:

@@ -280,4 +280,8 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # the command-line entry point; in-process callers of main() (tests,
+    # notebooks) keep their own JAX cache settings
+    from repro import compile_cache
+    compile_cache.configure()
     main()

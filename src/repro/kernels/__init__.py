@@ -6,6 +6,6 @@ secure_sum  — stage-1 wrapping uint32 reduction over the client axis
 dp_noise    — fused DP clip-scale + in-kernel Gaussian noise
 
 ops.py holds the jit'd public wrappers; ref.py the pure-jnp oracles.
-Kernels run in interpret mode on CPU (this container) and compile for TPU.
-EXAMPLE.md retained from the scaffold.
+Kernels run in interpret mode on the CPU backend (the test suite) and
+compile for the TPU everywhere else (``common.interpret_mode``).
 """

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.common import (LANES, ROW_BLOCK, global_index,
-                                  interpret_mode, kdf_u32)
+                                  interpret_mode, kdf_u32, u32_to_f32)
 
 TWO_PI = 6.2831853071795864
 
@@ -25,8 +25,8 @@ def _box_muller(k0, k1, ctr):
     b1 = kdf_u32(k0, k1, ctr * jnp.uint32(2))
     b2 = kdf_u32(k0, k1, ctr * jnp.uint32(2) + jnp.uint32(1))
     # u1 in (0, 1]: (b1 + 1) / 2^32 ; u2 in [0, 1)
-    u1 = (b1.astype(jnp.float32) + 1.0) * (1.0 / 4294967296.0)
-    u2 = b2.astype(jnp.float32) * (1.0 / 4294967296.0)
+    u1 = (u32_to_f32(b1) + 1.0) * (1.0 / 4294967296.0)
+    u2 = u32_to_f32(b2) * (1.0 / 4294967296.0)
     r = jnp.sqrt(-2.0 * jnp.log(u1))
     return r * jnp.cos(TWO_PI * u2)
 

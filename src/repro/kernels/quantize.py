@@ -11,20 +11,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import LANES, ROW_BLOCK, interpret_mode
+from repro.kernels.common import (LANES, ROW_BLOCK, f32_to_u32,
+                                  interpret_mode, u32_to_f32)
 
 
 def _quantize_kernel(x_ref, out_ref, *, clip, bits):
     lv = jnp.float32((1 << bits) - 1)
     xf = jnp.clip(x_ref[...].astype(jnp.float32), -clip, clip)
     q = jnp.round((xf + clip) / (2.0 * clip) * lv)
-    out_ref[...] = q.astype(jnp.uint32)
+    out_ref[...] = f32_to_u32(q)
 
 
 def _dequantize_kernel(q_ref, out_ref, *, clip, bits, n):
     # same op sequence as core.quantize.dequantize_sum (bit-exact)
     lv = jnp.float32((1 << bits) - 1)
-    mean_code = q_ref[...].astype(jnp.float32) / jnp.float32(n)
+    mean_code = u32_to_f32(q_ref[...]) / jnp.float32(n)
     out_ref[...] = (mean_code / lv) * (2.0 * clip) - clip
 
 

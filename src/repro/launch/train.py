@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
+from repro import compat, compile_cache
 from repro.checkpoint import save_checkpoint
 from repro.configs import get_config, get_reduced_config
 from repro.configs.shapes import InputShape
@@ -57,6 +57,7 @@ def main(argv=None):
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--insecure", action="store_true")
     args = ap.parse_args(argv)
+    compile_cache.configure()
 
     cfg = (get_reduced_config(args.arch) if args.reduced
            else get_config(args.arch))

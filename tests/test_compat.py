@@ -1,7 +1,6 @@
-"""JAX 0.4/0.5 compat shims: ambient-mesh introspection must behave
-identically across API generations, and the §Perf with-sharding-constraint
-helpers must be exact no-ops on unmeshed CPU under BOTH the old
-(physical_mesh) and new (get_abstract_mesh) APIs."""
+"""Mesh wrappers in ``repro.compat``: ambient-mesh introspection normalizes
+JAX's "no mesh" answers to None, and the §Perf with-sharding-constraint
+helpers are exact no-ops on unmeshed CPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -47,17 +46,16 @@ def test_new_api_preferred_when_present(monkeypatch):
 
 
 def test_new_api_empty_sentinel_falls_through(monkeypatch):
-    # 0.5's AbstractMesh() "no mesh" sentinel has no axes -> treated as
-    # unmeshed (the 0.4 physical-mesh fallback is also empty here).
+    # JAX's AbstractMesh() "no mesh" sentinel has no axes -> unmeshed.
     monkeypatch.setattr(jax.sharding, "get_abstract_mesh",
                         lambda: _FakeMesh((), ()), raising=False)
     assert compat.get_abstract_mesh() is None
 
 
-@pytest.mark.parametrize("api", ["old", "new_none", "new_empty"])
+@pytest.mark.parametrize("api", ["new_none", "new_empty"])
 def test_constraint_helpers_noop_unmeshed(monkeypatch, api):
     """model/attention/moe/fl_step mesh-constraint helpers: identity on
-    unmeshed CPU regardless of which JAX mesh API is available."""
+    unmeshed CPU whichever "no mesh" answer JAX gives."""
     if api == "new_none":
         monkeypatch.setattr(jax.sharding, "get_abstract_mesh", lambda: None,
                             raising=False)
@@ -82,6 +80,20 @@ def test_constraint_helpers_noop_unmeshed(monkeypatch, api):
     np.testing.assert_array_equal(np.asarray(f(x)), np.asarray(x))
 
 
-def test_make_mesh_works_without_axis_types():
+def test_make_mesh_axes_are_auto():
     mesh = compat.make_mesh((len(jax.devices()),), ("data",))
     assert mesh.shape["data"] == len(jax.devices())
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,)
+
+
+def test_shard_map_psum_uint32_over_mesh_axis():
+    """compat.shard_map runs a uint32 psum (the stage-2 limb merge's
+    collective) with the replication checker off."""
+    from jax.sharding import PartitionSpec as P
+    mesh = compat.make_mesh((len(jax.devices()),), ("pod",))
+    n = len(jax.devices())
+    x = jnp.arange(4 * n, dtype=jnp.uint32).reshape(n, 4)
+    f = compat.shard_map(lambda a: jax.lax.psum(a, "pod"), mesh=mesh,
+                         in_specs=P("pod"), out_specs=P())
+    np.testing.assert_array_equal(np.asarray(f(x)),
+                                  np.asarray(x).sum(0, keepdims=True))

@@ -94,3 +94,37 @@ def test_kernel_mask_cancellation_end_to_end(rng):
                         for i in range(n)])
     np.testing.assert_array_equal(ops.secure_sum(masked),
                                   ops.secure_sum(qs))
+
+
+def test_u32_to_f32_matches_direct_cast(rng):
+    """The int32 route Mosaic compiles rounds exactly like the direct
+    uint32 -> float32 cast the jnp references use (edges + random)."""
+    from repro.kernels.common import u32_to_f32
+    edges = np.array([0, 1, 0xFFFF, 0x10000, 2**24 - 1, 2**24, 2**24 + 1,
+                      2**24 + 3, 2**25 + 2, 2**31 - 1, 2**31, 2**31 + 1,
+                      2**32 - 129, 2**32 - 128, 2**32 - 1], np.uint32)
+    x = np.concatenate([edges, rng.randint(0, 2**32, 200_000,
+                                           dtype=np.uint64)
+                        .astype(np.uint32)])
+    got = np.asarray(u32_to_f32(jnp.asarray(x)))
+    np.testing.assert_array_equal(got.view(np.uint32),
+                                  x.astype(np.float32).view(np.uint32))
+
+
+def test_f32_to_u32_matches_direct_cast():
+    from repro.kernels.common import f32_to_u32
+    codes = np.concatenate([np.arange(0, 2**16), [2**24, 2**30, 2**31 - 128]]
+                           ).astype(np.float32)
+    np.testing.assert_array_equal(np.asarray(f32_to_u32(jnp.asarray(codes))),
+                                  codes.astype(np.uint32))
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                               ("tpu", False),
+                                               ("gpu", False)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, interpret):
+    """No silent interpreter fallback: only the CPU backend interprets."""
+    import jax
+    from repro.kernels.common import interpret_mode
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert interpret_mode() is interpret

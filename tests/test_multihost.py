@@ -7,7 +7,8 @@ fresh python with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
 in the subprocess env only, builds a real (pod=4, data=2) mesh, and runs
 the per_pod fl_round: the stage-2 combine must take the shard_map pod
 route (per-pod limb states + one uint32 psum across 4 pods) and training
-must still reduce loss. Marked ``multihost``; deselect with
+must still reduce loss. A second subprocess gives 4 devices to
+``chip_smoke.py``'s four-chip phase. Marked ``multihost``; deselect with
 ``-m 'not multihost'`` when iterating.
 """
 import os
@@ -61,15 +62,39 @@ print("MULTIHOST_OK", losses)
 """
 
 
-@pytest.mark.multihost
-def test_per_pod_round_on_emulated_8_device_host():
+_FOUR_CHIP_SCRIPT = r"""
+import jax
+assert jax.device_count() == 4, jax.devices()
+import chip_smoke
+out = chip_smoke.four_chip_phase(jax.devices())
+assert out["stage2_bit_identical"], out
+print("FOUR_CHIP_OK", out["losses"])
+"""
+
+
+def _run_emulated(script: str, n_devices: int) -> str:
     env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count="
+                         f"{n_devices}",
                JAX_PLATFORMS="cpu",
-               PYTHONPATH=os.path.join(REPO, "src"))
-    proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
+               PYTHONPATH=os.pathsep.join([os.path.join(REPO, "src"),
+                                           REPO]))
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
                           cwd=REPO, capture_output=True, text=True,
                           timeout=600)
     assert proc.returncode == 0, \
         f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"
-    assert "MULTIHOST_OK" in proc.stdout, proc.stdout
+    return proc.stdout
+
+
+@pytest.mark.multihost
+def test_per_pod_round_on_emulated_8_device_host():
+    assert "MULTIHOST_OK" in _run_emulated(_SCRIPT, 8)
+
+
+@pytest.mark.multihost
+def test_chip_smoke_four_chip_phase_on_emulated_4_device_host():
+    """``chip_smoke.py --four-chips``'s phase on 4 emulated devices: the
+    shard_map stage-2 route, falling loss, stage-2 bit-identity with the
+    zero-padded route, one pod shard per device."""
+    assert "FOUR_CHIP_OK" in _run_emulated(_FOUR_CHIP_SCRIPT, 4)

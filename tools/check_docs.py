@@ -88,8 +88,8 @@ _CODE_DIRS = ("src", "tools", "benchmarks", "examples")
 def check_module_map() -> list:
     """Every backtick-quoted ``*.py`` token in README.md must reference a
     real file: path-qualified tokens resolve from the repo root; bare
-    names must exist somewhere under the code trees. Keeps the module-map
-    table honest when files are renamed or split."""
+    names must exist at the root or somewhere under the code trees. Keeps
+    the module-map table honest when files are renamed or split."""
     errors = []
     readme = ROOT / "README.md"
     bare_index = None
@@ -102,9 +102,10 @@ def check_module_map() -> list:
         if bare_index is None:
             bare_index = {p.name for d in _CODE_DIRS
                           for p in (ROOT / d).rglob("*.py")}
+            bare_index |= {p.name for p in ROOT.glob("*.py")}
         if token not in bare_index:
-            errors.append(f"README.md: `{token}` not found under any of "
-                          f"{'/'.join(_CODE_DIRS)}")
+            errors.append(f"README.md: `{token}` not found at the repo root "
+                          f"or under any of {'/'.join(_CODE_DIRS)}")
     return errors
 
 
