@@ -131,8 +131,8 @@ def main(quick: bool = False):
         span_tree=root))
     pf = json.load(open(pf_path))
     names = {e["name"] for e in pf["traceEvents"] if e.get("ph") == "X"}
-    for stage in ("secure_agg", "cohort_interims", "dp", "quantize",
-                  "mask", "vg_sum", "limb_combine", "server_update"):
+    for stage in ("vg_plan", "secure_agg", "ravel_rows", "cohort_interims",
+                  "limb_combine", "server_update"):
         assert stage in names, f"stage {stage!r} missing from trace"
     print(f"# wrote {pf_path} and {fl_path}")
 

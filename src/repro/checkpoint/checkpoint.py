@@ -18,6 +18,8 @@ import os
 import jax
 import numpy as np
 
+from repro import tracing
+
 
 def _paths_and_leaves(tree):
     leaves_with_paths = jax.tree_util.tree_flatten_with_path(tree)[0]
@@ -38,16 +40,17 @@ def serialize_pytree(tree) -> bytes:
 def deserialize_pytree(blob: bytes, like=None):
     """If ``like`` is given, restore into its exact treedef; otherwise
     return {path: array}."""
-    with np.load(io.BytesIO(blob)) as z:
-        paths = json.loads(bytes(z["__paths__"]).decode())
-        leaves = [z[f"leaf_{i}"] for i in range(len(paths))]
-    if like is None:
-        return dict(zip(paths, leaves))
-    like_paths, _ = _paths_and_leaves(like)
-    if like_paths != paths:
-        raise ValueError("checkpoint structure mismatch")
-    treedef = jax.tree_util.tree_structure(like)
-    return jax.tree_util.tree_unflatten(treedef, leaves)
+    with tracing.span("deserialize"):
+        with np.load(io.BytesIO(blob)) as z:
+            paths = json.loads(bytes(z["__paths__"]).decode())
+            leaves = [z[f"leaf_{i}"] for i in range(len(paths))]
+        if like is None:
+            return dict(zip(paths, leaves))
+        like_paths, _ = _paths_and_leaves(like)
+        if like_paths != paths:
+            raise ValueError("checkpoint structure mismatch")
+        treedef = jax.tree_util.tree_structure(like)
+        return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def save_checkpoint(path: str, tree, step: int | None = None):

@@ -248,13 +248,24 @@ class CohortEngine:
             if w and len(client_ids) > w:
                 return self._run_waves(params, list(client_ids),
                                        round_idx, w)
-            batches = stack_trees([self.batch_fn(cid, round_idx)
-                                   for cid in client_ids])
+            batches = self._make_batches(params, client_ids,
+                                         [round_idx] * len(client_ids))
             if self.mesh is not None:
                 self._check_divisible(len(client_ids))
             deltas, losses = self._cohort_fn(False)(params, batches,
                                                     self.spec.frozen)
             return deltas, losses, self._n_samples(batches, stacked=True)
+
+    def _make_batches(self, params, client_ids, round_idxs):
+        """The clients' stacked batches (one round index per client), in a
+        ``make_batches`` span that counts the host bytes the cohort call
+        will send: these batches and any host leaves of ``params``."""
+        with tracing.span("make_batches", n=len(client_ids)) as sp:
+            batches = stack_trees([self.batch_fn(cid, r)
+                                   for cid, r in zip(client_ids,
+                                                     round_idxs)])
+            sp.transfer(h2d=(params, batches))
+        return batches
 
     def _run_waves(self, params, client_ids, round_idx: int, w: int):
         """Stream an oversized cohort through fixed-width ``w``-client
@@ -274,18 +285,23 @@ class CohortEngine:
                 chunk = chunk + [chunk[-1]] * (w - n_real)
             with tracing.span("train_wave", wave=s // w, w=w,
                               n_real=n_real):
-                batches = stack_trees([self.batch_fn(cid, round_idx)
-                                       for cid in chunk])
+                batches = self._make_batches(params, chunk,
+                                             [round_idx] * w)
                 deltas, losses = fn(params, batches, self.spec.frozen)
                 if n_samples is None:
                     n_samples = self._n_samples(batches, stacked=True)
-                host = jax.tree.map(np.asarray, deltas)
-                delta_parts.append(jax.tree.map(lambda a: a[:n_real],
-                                                host))
-                loss_parts.append(np.asarray(losses)[:n_real])
-        stacked = jax.tree.map(lambda *xs: np.concatenate(xs, axis=0),
-                               *delta_parts)
-        return stacked, jnp.asarray(np.concatenate(loss_parts)), n_samples
+                with tracing.span("wave_to_host") as sp:
+                    sp.transfer(d2h=(deltas, losses))
+                    host = jax.tree.map(np.asarray, deltas)
+                    delta_parts.append(jax.tree.map(lambda a: a[:n_real],
+                                                    host))
+                    loss_parts.append(np.asarray(losses)[:n_real])
+        with tracing.span("stack_waves") as sp:
+            stacked = jax.tree.map(lambda *xs: np.concatenate(xs, axis=0),
+                                   *delta_parts)
+            losses = np.concatenate(loss_parts)
+            sp.transfer(h2d=losses)
+            return stacked, jnp.asarray(losses), n_samples
 
     def run_cohort_personalized(self, params_list, client_ids, round_idxs):
         """Per-client params (clustered FL branches, async mixed-version
@@ -308,9 +324,8 @@ class CohortEngine:
                           personalized=True):
             stacked_params = jax.tree.map(lambda *xs: jnp.stack(xs),
                                           *params_list)
-            batches = stack_trees([self.batch_fn(cid, r)
-                                   for cid, r in zip(client_ids,
-                                                     round_idxs)])
+            batches = self._make_batches(params_list, client_ids,
+                                         round_idxs)
             if self.mesh is not None:
                 self._check_divisible(len(client_ids))
             deltas, losses = self._cohort_fn(True)(stacked_params, batches,

@@ -290,20 +290,23 @@ def run_sync_round_stacked(params, strategy, strategy_state,
     exactly as in :func:`run_sync_round`."""
     key, round_seed = _round_randomness(key, round_seed, round_idx)
     cids = list(client_ids)
-    order = sorted(range(len(cids)), key=cids.__getitem__)
-    if order != list(range(len(cids))):
-        # protocol (and DP key-fold) order is sorted-cid — reorder rows
-        # with one gather per leaf rather than per client
-        idx = jnp.asarray(order)
-        stacked_updates = jax.tree.map(lambda a: a[idx], stacked_updates)
-    cids_sorted = [cids[j] for j in order]
-    protocol_order = sorted(cohort) if cohort is not None else cids_sorted
-    n_dropped = len(protocol_order) - len(cids_sorted)
-    cohort_set = set(protocol_order)
-    if n_dropped < 0 or any(c not in cohort_set for c in cids_sorted):
-        raise ValueError("client_ids must be a subset of cohort")
-    plan = make_virtual_groups(protocol_order, vg_size, seed=round_idx)
-    n_shards = sa.resolve_master_shards(len(plan.groups), secure_cfg)
+    with tracing.span("vg_plan", n=len(cids)):
+        order = sorted(range(len(cids)), key=cids.__getitem__)
+        if order != list(range(len(cids))):
+            # protocol (and DP key-fold) order is sorted-cid — reorder
+            # rows with one gather per leaf rather than per client
+            idx = jnp.asarray(order)
+            stacked_updates = jax.tree.map(lambda a: a[idx],
+                                           stacked_updates)
+        cids_sorted = [cids[j] for j in order]
+        protocol_order = sorted(cohort) if cohort is not None \
+            else cids_sorted
+        n_dropped = len(protocol_order) - len(cids_sorted)
+        cohort_set = set(protocol_order)
+        if n_dropped < 0 or any(c not in cohort_set for c in cids_sorted):
+            raise ValueError("client_ids must be a subset of cohort")
+        plan = make_virtual_groups(protocol_order, vg_size, seed=round_idx)
+        n_shards = sa.resolve_master_shards(len(plan.groups), secure_cfg)
     stats: dict = {}
 
     if compressor is not None:

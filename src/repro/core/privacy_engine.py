@@ -122,44 +122,57 @@ def _interims_body(flat, round_seed, key, rows_t, vgs_t, alive,
     way, so a survivor's code — key-folded at its FULL-cohort row — is
     bit-identical whether or not anyone else dropped."""
     n = flat.shape[0]
-    flat = flat.astype(jnp.float32)
-
     # per-client DP, vmapped over the client axis; key folding follows the
     # row order (== sorted-cid order in the orchestrator), matching the
     # serial reference's fold_in(key, j) exactly.
-    if dp_cfg.mechanism == "local":
-        sigma = float(dp_cfg.noise_multiplier * dp_cfg.clip_norm) \
-            if dp_cfg.noise_multiplier > 0 else 0.0
-        keys = jax.vmap(lambda j: jax.random.fold_in(key, j))(
-            jnp.arange(n, dtype=jnp.uint32))
-        flat = jax.vmap(partial(dp_mod.flat_local_dp,
-                                clip_norm=float(dp_cfg.clip_norm),
-                                sigma=sigma))(flat, keys)
-    elif dp_cfg.mechanism == "global":
-        # clip here; the server-side noise is added to the combined mean by
-        # the orchestrator (it is one draw, not a per-client stage)
-        flat = jax.vmap(partial(dp_mod.flat_clip,
-                                clip_norm=float(dp_cfg.clip_norm)))(flat)
-
-    qs = quantize(flat, secure_cfg.clip, secure_cfg.bits)   # (n, size) u32
+    flat = _dp_rows(flat, jnp.arange(n, dtype=jnp.uint32), key, dp_cfg)
+    with jax.named_scope("quantize"):
+        qs = quantize(flat, secure_cfg.clip, secure_cfg.bits)  # (n, size)
 
     interims = []
     for (g, m), rows, vgs in zip(bucket_shapes, rows_t, vgs_t):
-        qb = qs[rows]                                       # (m*g, size)
+        masked = _mask_rows(qs[rows], round_seed, vgs, g, m, secure_cfg)
+        with jax.named_scope("vg_sum"):
+            if alive is not None:
+                masked = jnp.where(alive[rows][:, None], masked,
+                                   jnp.zeros((), U32))
+            interims.append(masking.vg_sums(masked, g))     # (m, size)
+    return jnp.concatenate(interims, axis=0)                # (G, size)
+
+
+def _dp_rows(flat, row_ids, key, dp_cfg):
+    """Stage 1 of the chain, named ``dp`` on the device: per-client DP on
+    (n, size) rows, the key folded at each row's global ``row_ids``."""
+    with jax.named_scope("dp"):
+        flat = flat.astype(jnp.float32)
+        if dp_cfg.mechanism == "local":
+            sigma = float(dp_cfg.noise_multiplier * dp_cfg.clip_norm) \
+                if dp_cfg.noise_multiplier > 0 else 0.0
+            keys = jax.vmap(lambda j: jax.random.fold_in(key, j))(row_ids)
+            flat = jax.vmap(partial(dp_mod.flat_local_dp,
+                                    clip_norm=float(dp_cfg.clip_norm),
+                                    sigma=sigma))(flat, keys)
+        elif dp_cfg.mechanism == "global":
+            # clip here; the server-side noise is added to the combined
+            # mean by the orchestrator (one draw, not a per-client stage)
+            flat = jax.vmap(partial(dp_mod.flat_clip,
+                                    clip_norm=float(dp_cfg.clip_norm)))(flat)
+        return flat
+
+
+def _mask_rows(qb, round_seed, vgs, g, m, secure_cfg):
+    """Stage 3, named ``mask`` on the device: the pairwise masks of ``m``
+    whole groups of ``g`` rows (``qb`` group-major, ``vgs`` their plan
+    ids)."""
+    with jax.named_scope("mask"):
         gseeds = jnp.repeat(
             jax.vmap(lambda v: group_seed(round_seed, v))(vgs),
             g, axis=0)                                      # (m*g, 2)
         idxs = jnp.tile(jnp.arange(g, dtype=U32), m)
         if secure_cfg.use_kernels:
             from repro.kernels import ops
-            masked = ops.mask_apply_cohort(qb, idxs, gseeds, g)
-        else:
-            masked = masking.protect_cohort_grouped(qb, idxs, gseeds, g)
-        if alive is not None:
-            masked = jnp.where(alive[rows][:, None], masked,
-                               jnp.zeros((), U32))
-        interims.append(masking.vg_sums(masked, g))         # (m, size)
-    return jnp.concatenate(interims, axis=0)                # (G, size)
+            return ops.mask_apply_cohort(qb, idxs, gseeds, g)
+        return masking.protect_cohort_grouped(qb, idxs, gseeds, g)
 
 
 @partial(jax.jit,
@@ -218,28 +231,13 @@ def _wave_limb_state(wave_flat, row_ids, round_seed, key, vgs, real, *,
     merging through the shared executables is bit-identical to the
     whole-cohort dispatch."""
     m = vgs.shape[0]
-    flat = wave_flat.astype(jnp.float32)
-    if dp_cfg.mechanism == "local":
-        sigma = float(dp_cfg.noise_multiplier * dp_cfg.clip_norm) \
-            if dp_cfg.noise_multiplier > 0 else 0.0
-        keys = jax.vmap(lambda j: jax.random.fold_in(key, j))(row_ids)
-        flat = jax.vmap(partial(dp_mod.flat_local_dp,
-                                clip_norm=float(dp_cfg.clip_norm),
-                                sigma=sigma))(flat, keys)
-    elif dp_cfg.mechanism == "global":
-        flat = jax.vmap(partial(dp_mod.flat_clip,
-                                clip_norm=float(dp_cfg.clip_norm)))(flat)
-    qs = quantize(flat, secure_cfg.clip, secure_cfg.bits)
-    gseeds = jnp.repeat(
-        jax.vmap(lambda v: group_seed(round_seed, v))(vgs), g, axis=0)
-    idxs = jnp.tile(jnp.arange(g, dtype=U32), m)
-    if secure_cfg.use_kernels:
-        from repro.kernels import ops
-        masked = ops.mask_apply_cohort(qs, idxs, gseeds, g)
-    else:
-        masked = masking.protect_cohort_grouped(qs, idxs, gseeds, g)
-    interims = masking.vg_sums(masked, g)                   # (m, size)
-    interims = jnp.where(real[:, None], interims, jnp.zeros((), U32))
+    flat = _dp_rows(wave_flat, row_ids, key, dp_cfg)
+    with jax.named_scope("quantize"):
+        qs = quantize(flat, secure_cfg.clip, secure_cfg.bits)
+    masked = _mask_rows(qs, round_seed, vgs, g, m, secure_cfg)
+    with jax.named_scope("vg_sum"):
+        interims = masking.vg_sums(masked, g)               # (m, size)
+        interims = jnp.where(real[:, None], interims, jnp.zeros((), U32))
     return interim_limb_state(interims, secure_cfg.limbs)
 
 
@@ -268,10 +266,11 @@ def _waved_states(flat, buckets, round_seed, key, wave, secure_cfg, dp_cfg):
                                         np.repeat(chunk[-1:], pad, axis=0)])
                 cv = np.concatenate([cv, np.repeat(cv[-1:], pad)])
             with tracing.span("wave", wave=len(states), g=b.g,
-                              n_groups=m_real) \
-                    .mark_fused("dp", "quantize", "mask", "vg_sum"):
+                              n_groups=m_real) as sp:
+                rows_w = flat[chunk.ravel()]
+                sp.transfer(h2d=rows_w)
                 states.append(_wave_limb_state(
-                    jnp.asarray(flat[chunk.ravel()]),
+                    jnp.asarray(rows_w),
                     jnp.asarray(chunk.ravel().astype(np.uint32)),
                     round_seed, key, jnp.asarray(cv),
                     jnp.asarray(np.arange(m_w) < m_real),
@@ -332,15 +331,26 @@ def aggregate_flat(flat, plan, client_order, round_seed, *,
     bit-identical to a clean round over the survivors (same DP key-fold
     rows). ``stats``: optional dict, receives ``n_dropped``/``recovery_s``
     from the recovery step."""
-    buckets = plan_buckets(plan, client_order)
-    n_shards = _check_plan(buckets, secure_cfg, n_shards)
+    with tracing.span("secure_agg", n=flat.shape[0]) as sp:
+        return _aggregate_flat(sp, flat, plan, client_order, round_seed,
+                               secure_cfg, dp_cfg, key, n_shards, alive,
+                               stats)
+
+
+def _aggregate_flat(sp, flat, plan, client_order, round_seed, secure_cfg,
+                    dp_cfg, key, n_shards, alive, stats):
+    """:func:`aggregate_flat` inside its open ``secure_agg`` span ``sp``,
+    which it tags with the stage-2 route."""
+    with tracing.span("vg_plan", n=flat.shape[0]):
+        buckets = plan_buckets(plan, client_order)
+        n_shards = _check_plan(buckets, secure_cfg, n_shards)
+        rows_t = tuple(jnp.asarray(b.rows, jnp.int32) for b in buckets)
+        vgs_t = tuple(jnp.asarray(b.vg_ids, U32) for b in buckets)
+    bucket_shapes = tuple((b.g, b.n_groups) for b in buckets)
     n = flat.shape[0]
     if key is None:
         key = jax.random.PRNGKey(0)
     round_seed = jnp.asarray(round_seed, U32)
-    rows_t = tuple(jnp.asarray(b.rows, jnp.int32) for b in buckets)
-    vgs_t = tuple(jnp.asarray(b.vg_ids, U32) for b in buckets)
-    bucket_shapes = tuple((b.g, b.n_groups) for b in buckets)
     if alive is None:
         wave = int(getattr(secure_cfg, "wave_clients", 0))
         if 0 < wave < n:
@@ -350,26 +360,25 @@ def aggregate_flat(flat, plan, client_order, round_seed, *,
             # the same shared executable)
             if stats is not None:
                 stats["stage2_route"] = "waved"
-            with tracing.span("secure_agg", route="waved", n=n,
-                              n_shards=n_shards):
-                states = _waved_states(flat, buckets, round_seed, key,
-                                       wave, secure_cfg, dp_cfg)
-                check_shard_headroom(states.shape[0])
-                with tracing.span("limb_combine",
-                                  n_states=int(states.shape[0])):
-                    return combine_limb_states(states, n, secure_cfg)
+            sp.set(route="waved", n_shards=n_shards)
+            sp.transfer(d2h=flat)
+            states = _waved_states(flat, buckets, round_seed, key,
+                                   wave, secure_cfg, dp_cfg)
+            check_shard_headroom(states.shape[0])
+            with tracing.span("limb_combine",
+                              n_states=int(states.shape[0])):
+                return combine_limb_states(states, n, secure_cfg)
         if stats is not None:
             stats["stage2_route"] = "single_dispatch"
-        with tracing.span("secure_agg", route="single_dispatch", n=n,
-                          n_shards=n_shards):
-            with tracing.span("cohort_interims", n=n) \
-                    .mark_fused("dp", "quantize", "mask", "vg_sum"):
-                states = _cohort_interims(
-                    jnp.asarray(flat), round_seed, key, rows_t, vgs_t,
-                    bucket_shapes=bucket_shapes, n_shards=n_shards,
-                    secure_cfg=secure_cfg, dp_cfg=dp_cfg)
-            with tracing.span("limb_combine", n_shards=n_shards):
-                return combine_limb_states(states, n, secure_cfg)
+        sp.set(route="single_dispatch", n_shards=n_shards)
+        with tracing.span("cohort_interims", n=n) as isp:
+            isp.transfer(h2d=flat)
+            states = _cohort_interims(
+                jnp.asarray(flat), round_seed, key, rows_t, vgs_t,
+                bucket_shapes=bucket_shapes, n_shards=n_shards,
+                secure_cfg=secure_cfg, dp_cfg=dp_cfg)
+        with tracing.span("limb_combine", n_shards=n_shards):
+            return combine_limb_states(states, n, secure_cfg)
 
     from repro.core import dropout
     alive = np.asarray(alive, bool)
@@ -404,21 +413,20 @@ def aggregate_flat(flat, plan, client_order, round_seed, *,
         stats["n_voided_groups"] = n_voided_groups
         stats["stage2_route"] = "churn_recovery"
     n_survivors = int(alive.sum())
-    with tracing.span("secure_agg", route="churn_recovery", n=n,
-                      n_survivors=n_survivors, n_shards=n_shards):
-        with tracing.span("cohort_interims", n=n, churn=True) \
-                .mark_fused("dp", "quantize", "mask", "vg_sum"):
-            interims = _cohort_interims_churn(
-                jnp.asarray(flat), round_seed, key, rows_t, vgs_t,
-                jnp.asarray(alive), bucket_shapes=bucket_shapes,
-                secure_cfg=secure_cfg, dp_cfg=dp_cfg)
-        with tracing.span("mask_recovery",
-                          n_dropped=n - n_survivors):
-            interims = dropout.recover_interims(interims, buckets, alive,
-                                                round_seed, stats=stats)
-        with tracing.span("limb_combine", n_shards=n_shards):
-            states = _shard_limbs_jit(interims, n_shards, secure_cfg.limbs)
-            return combine_limb_states(states, n_survivors, secure_cfg)
+    sp.set(route="churn_recovery", n_survivors=n_survivors,
+           n_shards=n_shards)
+    with tracing.span("cohort_interims", n=n, churn=True) as isp:
+        isp.transfer(h2d=flat)
+        interims = _cohort_interims_churn(
+            jnp.asarray(flat), round_seed, key, rows_t, vgs_t,
+            jnp.asarray(alive), bucket_shapes=bucket_shapes,
+            secure_cfg=secure_cfg, dp_cfg=dp_cfg)
+    with tracing.span("mask_recovery", n_dropped=n - n_survivors):
+        interims = dropout.recover_interims(interims, buckets, alive,
+                                            round_seed, stats=stats)
+    with tracing.span("limb_combine", n_shards=n_shards):
+        states = _shard_limbs_jit(interims, n_shards, secure_cfg.limbs)
+        return combine_limb_states(states, n_survivors, secure_cfg)
 
 
 def aggregate_stacked(stacked_updates, plan, client_order, round_seed, *,
@@ -436,23 +444,29 @@ def aggregate_stacked(stacked_updates, plan, client_order, round_seed, *,
     mask excludes), so each survivor keeps the DP key-fold of its
     selection-time row and the recovered mean is bit-identical to a clean
     round over the survivors."""
-    flat = ravel_rows(stacked_updates)
     template = jax.tree.map(lambda a: a[0], stacked_updates)
     _, unflatten = raveling.cached_unflatten(template)
-    alive = None
-    if cohort_order is not None and list(cohort_order) != list(client_order):
-        cohort_order = list(cohort_order)
-        pos_of = {cid: j for j, cid in enumerate(cohort_order)}
-        positions = jnp.asarray([pos_of[c] for c in client_order],
-                                jnp.int32)
-        full = jnp.zeros((len(cohort_order), flat.shape[1]), flat.dtype)
-        flat = full.at[positions].set(flat)
-        alive = np.zeros(len(cohort_order), bool)
-        alive[np.asarray(positions)] = True
-        client_order = cohort_order
-    mean_flat = aggregate_flat(flat, plan, client_order, round_seed,
-                               secure_cfg=secure_cfg, dp_cfg=dp_cfg,
-                               key=key, alive=alive, stats=stats)
+    with tracing.span("secure_agg", n=len(client_order)) as sp:
+        with tracing.span("ravel_rows") as rsp:
+            rsp.transfer(h2d=stacked_updates)
+            flat = ravel_rows(stacked_updates)
+        alive = None
+        if cohort_order is not None \
+                and list(cohort_order) != list(client_order):
+            with tracing.span("scatter_survivors", n=len(client_order)):
+                cohort_order = list(cohort_order)
+                pos_of = {cid: j for j, cid in enumerate(cohort_order)}
+                positions = jnp.asarray([pos_of[c] for c in client_order],
+                                        jnp.int32)
+                full = jnp.zeros((len(cohort_order), flat.shape[1]),
+                                 flat.dtype)
+                flat = full.at[positions].set(flat)
+                alive = np.zeros(len(cohort_order), bool)
+                alive[np.asarray(positions)] = True
+                client_order = cohort_order
+        mean_flat = _aggregate_flat(sp, flat, plan, client_order,
+                                    round_seed, secure_cfg, dp_cfg, key,
+                                    None, alive, stats)
     return unflatten(mean_flat)
 
 

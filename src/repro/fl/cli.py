@@ -30,7 +30,7 @@ import pickle
 import sys
 
 from repro.core.dp import DPConfig
-from repro.fl import tracing
+from repro import tracing
 from repro.fl.dashboard import (render_fleet, render_metrics,
                                 render_status, render_task_list,
                                 render_task_view, render_trace)

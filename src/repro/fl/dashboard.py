@@ -155,7 +155,6 @@ def render_trace(svc, task_id: int, last: int = 8) -> str:
             parts.append(f"wall={ev['wall_ms']:.1f}ms")
         lines.append(f"  {head}  " + " ".join(parts))
         for st in ev.get("stages", []):
-            fused = " (fused)" if st.get("fused") else ""
             lines.append(f"    {'  ' * st['depth']}{st['name']:<20} "
-                         f"{st['dur_ms']:>9.3f}ms{fused}")
+                         f"{st['dur_ms']:>9.3f}ms")
     return "\n".join(lines)

@@ -214,7 +214,10 @@ class ManagementService:
                                              device_info=device_info)
 
     def model_snapshot(self, task_id: int) -> bytes:
-        return serialize_pytree(self._tasks[task_id].model)
+        model = self._tasks[task_id].model
+        with tracing.span("snapshot", task=task_id) as sp:
+            sp.transfer(d2h=model)
+            return serialize_pytree(model)
 
     def submit_update(self, task_id: int, client_id: str, update,
                       n_samples: int, metrics=None,
@@ -274,23 +277,25 @@ class ManagementService:
         Returns True if this report CLOSED the round: aggregated over the
         survivors, or voided it (every member dropped — the round index is
         not consumed and the next ``begin_round`` re-selects)."""
-        rec = self._tasks[task_id]
-        coll = self._collectors.get(task_id)
-        if coll is None or client_id not in coll.cohort \
-                or client_id in coll.dropped or client_id in coll.results:
+        with tracing.span("report_dropout", task=task_id):
+            rec = self._tasks[task_id]
+            coll = self._collectors.get(task_id)
+            if coll is None or client_id not in coll.cohort \
+                    or client_id in coll.dropped \
+                    or client_id in coll.results:
+                return False
+            coll.dropped.add(client_id)
+            self.selection.drop(rec, client_id)
+            if coll.complete():
+                if coll.results:
+                    self._run_sync_aggregation(rec, coll)
+                else:
+                    # every member dropped: void the round (no survivors
+                    # to aggregate); dropped members re-enter the pool at
+                    # the next begin_round
+                    self._void_round(rec, coll)
+                return True
             return False
-        coll.dropped.add(client_id)
-        self.selection.drop(rec, client_id)
-        if coll.complete():
-            if coll.results:
-                self._run_sync_aggregation(rec, coll)
-            else:
-                # every member dropped: void the round (no survivors to
-                # aggregate); dropped members re-enter the pool at the
-                # next begin_round
-                self._void_round(rec, coll)
-            return True
-        return False
 
     def backfill_round(self, task_id: int, unavailable, available=None
                        ) -> list:
@@ -480,14 +485,17 @@ class ManagementService:
         rec = self._tasks[task_id]
         if rec.status is not TaskStatus.RUNNING:
             return rec.round_idx, []
-        self.selection.reset_round(rec)   # last round's selected/done/dropped
-        with tracing.span("selection", task=task_id,
-                          round=rec.round_idx) as sp:
-            cohort = self.selection.select_cohort(
-                rec, overprovision=rec.config.overprovision,
-                deadline=rec.config.round_timeout_s, available=available)
-            sp.set(n_cohort=len(cohort))
-        self._collectors[task_id] = _RoundCollector(rec.round_idx, cohort)
+        with tracing.span("begin_round", task=task_id, round=rec.round_idx):
+            self.selection.reset_round(rec)   # last round's selected/done
+            with tracing.span("selection", task=task_id,
+                              round=rec.round_idx) as sp:
+                cohort = self.selection.select_cohort(
+                    rec, overprovision=rec.config.overprovision,
+                    deadline=rec.config.round_timeout_s,
+                    available=available)
+                sp.set(n_cohort=len(cohort))
+            self._collectors[task_id] = _RoundCollector(rec.round_idx,
+                                                        cohort)
         return rec.round_idx, cohort
 
     def _void_round(self, rec: TaskRecord, coll: _RoundCollector,
@@ -553,47 +561,53 @@ class ManagementService:
             metrics=_churn_metrics(info)))
 
     def _finish_round(self, rec: TaskRecord, metrics: dict):
-        rec.history.append({"round": rec.round_idx, **metrics})
-        self.metrics.log(rec.task_id, rec.round_idx, **metrics)
-        tid = rec.task_id
-        self.meters.counter("rounds_completed", task=tid).inc()
-        # shape-contract probe: new compiled executables since the last
-        # finished round across the shared jitted entry points
-        cur = tracing.jit_cache_total()
-        self.meters.counter("jit_cache_misses").inc(
-            max(0, cur - self._jit_snapshot))
-        self._jit_snapshot = cur
-        if "upload_bytes_per_client" in metrics:
-            self.meters.histogram("upload_bytes_per_client", task=tid) \
-                .observe(metrics["upload_bytes_per_client"])
-        if "recovery_s" in metrics:
-            self.meters.histogram("recovery_s", task=tid) \
-                .observe(metrics["recovery_s"])
-        if self.flight is not None and rec.config.mode == "async":
-            # async rounds close inside _finish_round (no collector /
-            # aggregate span to lift a transcript from)
-            self.flight.record(tid, {
-                "event": "server_step", "round": rec.round_idx,
-                "metrics": {k: v for k, v in metrics.items()
-                            if isinstance(v, (int, float, str))}})
-        acc = self._accountants.get(rec.task_id)
-        if acc is not None:
-            pool = max(1, len(self.selection.registered(rec)))
-            # mode-correct sample rate: an async server step composes over
-            # the buffer_size clients that filled the FedBuff buffer; a
-            # sync round over the clients whose data actually entered the
-            # aggregate — the REALIZED participation ("n" = survivors),
-            # not clients_per_round, which over-provisioned cohorts exceed
-            # (using the config target would under-report epsilon)
-            per_step = (rec.config.buffer_size
-                        if rec.config.mode == "async"
-                        else metrics.get("n", rec.config.clients_per_round))
-            acc.q = min(1.0, per_step / pool)
-            acc.step()
-            eps = self.epsilon(rec.task_id)
-            if eps is not None:
-                self.meters.gauge("epsilon_spent", task=tid).set(eps)
-        self.check_stop(rec.task_id)
+        with tracing.span("finish_round", task=rec.task_id,
+                          round=rec.round_idx):
+            rec.history.append({"round": rec.round_idx, **metrics})
+            self.metrics.log(rec.task_id, rec.round_idx, **metrics)
+            tid = rec.task_id
+            self.meters.counter("rounds_completed", task=tid).inc()
+            # shape-contract probe: new compiled executables since the
+            # last finished round across the shared jitted entry points
+            cur = tracing.jit_cache_total()
+            self.meters.counter("jit_cache_misses").inc(
+                max(0, cur - self._jit_snapshot))
+            self._jit_snapshot = cur
+            if "upload_bytes_per_client" in metrics:
+                self.meters.histogram("upload_bytes_per_client",
+                                      task=tid) \
+                    .observe(metrics["upload_bytes_per_client"])
+            if "recovery_s" in metrics:
+                self.meters.histogram("recovery_s", task=tid) \
+                    .observe(metrics["recovery_s"])
+            if self.flight is not None and rec.config.mode == "async":
+                # async rounds close inside _finish_round (no collector /
+                # aggregate span to lift a transcript from)
+                self.flight.record(tid, {
+                    "event": "server_step", "round": rec.round_idx,
+                    "metrics": {k: v for k, v in metrics.items()
+                                if isinstance(v, (int, float, str))}})
+            acc = self._accountants.get(rec.task_id)
+            if acc is not None:
+                with tracing.span("accountant", task=tid):
+                    self._step_accountant(rec, acc, metrics)
+            self.check_stop(rec.task_id)
+
+    def _step_accountant(self, rec: TaskRecord, acc, metrics: dict):
+        pool = max(1, len(self.selection.registered(rec)))
+        # mode-correct sample rate: an async server step composes over the
+        # buffer_size clients that filled the FedBuff buffer; a sync round
+        # over the clients whose data actually entered the aggregate — the
+        # REALIZED participation ("n" = survivors), not clients_per_round,
+        # which over-provisioned cohorts exceed (using the config target
+        # would under-report epsilon)
+        per_step = (rec.config.buffer_size if rec.config.mode == "async"
+                    else metrics.get("n", rec.config.clients_per_round))
+        acc.q = min(1.0, per_step / pool)
+        acc.step()
+        eps = self.epsilon(rec.task_id)
+        if eps is not None:
+            self.meters.gauge("epsilon_spent", task=rec.task_id).set(eps)
 
     def check_stop(self, task_id: int):
         """Evaluate the task's stop criteria; on the first one met,

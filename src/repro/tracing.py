@@ -6,27 +6,35 @@ Three instruments, stdlib-only so ``repro.core`` modules can import it
 without dependency cycles:
 
 ``span``/``Tracer``
-    A span-based tracer: ``with tracing.span("mask_apply", task_id=3):``
+    A span-based tracer: ``with tracing.span("snapshot", task=3):``
     opens a timed span (monotonic wall clock via ``perf_counter`` + CPU
     clock via ``process_time``) that nests under whatever span is open on
-    the SAME thread — the per-thread stack makes the selection -> train ->
-    DP -> quantize -> mask -> VG sum -> limb combine tree fall out of the
-    call structure with no plumbing. Finished top-level spans collect on
-    the tracer (lock-protected; safe with the simulator's threads) and
-    export as Chrome/Perfetto ``trace_events`` JSON (:meth:`Tracer
-    .to_perfetto`) for timeline inspection in ``ui.perfetto.dev``.
+    the SAME thread — the per-thread stack makes the service -> local
+    training -> privacy chain tree fall out of the call structure with no
+    plumbing. Finished top-level spans collect on the tracer
+    (lock-protected; safe with the simulator's threads) and export as
+    Chrome/Perfetto ``trace_events`` JSON (:meth:`Tracer.to_perfetto`).
+
+    ``Tracer(profiler=True)`` also mirrors every span into the JAX
+    profiler: while open, a span is a ``jax.profiler.TraceAnnotation``
+    named ``florida.<span name>``, so a profiler trace holds the program's
+    stages and the device's operations on one clock. JAX is imported only
+    in that mode; this module stays importable without it.
+
+    A span around a jitted call times the HOST side: the dispatch, plus
+    whatever sync falls inside it. Device time lives in the profiler
+    trace's module and op events; stages fused into one program
+    (DP/quantize/mask/VG-sum in ``privacy_engine``) are named there by
+    ``jax.named_scope``, not by host spans.
+
+    Spans that move data between host and device carry ``h2d_bytes`` /
+    ``d2h_bytes`` attributes (:meth:`Span.transfer`, at the site of the
+    transfer: a host array handed to a jitted call or ``jnp.asarray`` is
+    h2d, a device array pulled by ``np.asarray``/``device_get`` is d2h).
 
     The default tracer is a :class:`NullTracer` whose ``span()`` returns a
     shared no-op context manager — library callers pay one dict build and
-    one method call per span site (``bench_trace`` pins the end-to-end
-    cost at < 2% of a 256-client sync round, tracing ON; off is noise).
-
-    Stages fused into ONE jitted dispatch (DP/quantize/mask/VG-sum inside
-    ``privacy_engine._cohort_interims``) cannot be separately timed
-    without breaking the one-program contract; ``Span.mark_fused`` emits
-    them as child spans sharing the dispatch window, tagged
-    ``fused=True`` — the timeline shows the real stage tree and is honest
-    about what XLA fused.
+    one method call per span site, and ``transfer`` counts nothing.
 
 ``FlightRecorder``
     A per-task JSONL round transcript: every closed round appends one
@@ -51,6 +59,7 @@ import os
 import sys
 import threading
 import time
+import weakref
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any
@@ -58,6 +67,9 @@ from typing import Any
 # ----------------------------------------------------------------------
 # spans
 # ----------------------------------------------------------------------
+
+
+PROFILER_PREFIX = "florida."
 
 
 @dataclass
@@ -72,9 +84,8 @@ class Span:
     cpu1: float = 0.0
     thread: int = 0
     children: list = field(default_factory=list)
-    fused: bool = False
     _tracer: Any = None
-    _fused_names: tuple = ()
+    _annotation: Any = None
 
     @property
     def wall_s(self) -> float:
@@ -89,15 +100,22 @@ class Span:
         self.attrs.update(attrs)
         return self
 
-    def mark_fused(self, *names):
-        """Declare stages that ran INSIDE this span's single compiled
-        dispatch: on exit each becomes a child span sharing this span's
-        window with ``fused=True`` (they cannot be separately timed
-        without splitting the XLA program)."""
-        self._fused_names = tuple(names)
+    def transfer(self, *, h2d=None, d2h=None):
+        """Add the bytes this span moves: ``h2d`` a pytree whose HOST
+        leaves go to the device, ``d2h`` one whose DEVICE leaves come to
+        the host (leaves on the other side move nothing and count 0)."""
+        if h2d is not None:
+            self.attrs["h2d_bytes"] = self.attrs.get("h2d_bytes", 0) \
+                + tree_bytes(h2d, on_device=False)
+        if d2h is not None:
+            self.attrs["d2h_bytes"] = self.attrs.get("d2h_bytes", 0) \
+                + tree_bytes(d2h, on_device=True)
         return self
 
     def __enter__(self):
+        if self._tracer.profiler:
+            self._annotation = _trace_annotation(PROFILER_PREFIX + self.name)
+            self._annotation.__enter__()
         self.t0 = time.perf_counter()
         self.cpu0 = time.process_time()
         self.thread = threading.get_ident()
@@ -107,20 +125,32 @@ class Span:
     def __exit__(self, *exc):
         self.t1 = time.perf_counter()
         self.cpu1 = time.process_time()
-        for nm in self._fused_names:
-            self.children.append(Span(
-                name=nm, attrs={"fused": True}, t0=self.t0, t1=self.t1,
-                cpu0=self.cpu0, cpu1=self.cpu1, thread=self.thread,
-                fused=True))
         self._tracer._pop(self)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         return False
+
+
+def _trace_annotation(name: str):
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
+
+
+def tree_bytes(tree, *, on_device: bool) -> int:
+    """Bytes of a pytree's array leaves that live on the device
+    (``on_device``) or on the host: a ``jax.Array`` is on the device,
+    anything else with ``nbytes`` (NumPy) on the host."""
+    from jax.tree_util import tree_leaves
+    return sum(int(leaf.nbytes) for leaf in tree_leaves(tree)
+               if hasattr(leaf, "nbytes")
+               and hasattr(leaf, "devices") == on_device)
 
 
 class _NullSpan:
     """Shared do-nothing span: the only object the default tracer hands
     out, so uninstrumented runs allocate nothing per span site."""
     __slots__ = ()
-    fused = False
     name = ""
     attrs: dict = {}
     children: list = []
@@ -130,7 +160,7 @@ class _NullSpan:
     def set(self, **attrs):
         return self
 
-    def mark_fused(self, *names):
+    def transfer(self, *, h2d=None, d2h=None):
         return self
 
     def __enter__(self):
@@ -162,11 +192,14 @@ class Tracer:
     """Collecting tracer. Thread-safe: each thread keeps its own open-span
     stack (nesting = call structure per thread); finished top-level spans
     append to a lock-protected list. ``max_spans`` bounds memory — spans
-    past it are counted in ``n_dropped`` instead of stored."""
+    past it are counted in ``n_dropped`` instead of stored.
+    ``profiler``: mirror every span into the JAX profiler as a
+    ``TraceAnnotation`` named ``florida.<span name>``."""
     enabled = True
 
-    def __init__(self, max_spans: int = 200_000):
+    def __init__(self, max_spans: int = 200_000, profiler: bool = False):
         self.max_spans = max_spans
+        self.profiler = profiler
         self.n_dropped = 0
         self.n_spans = 0
         self.epoch = time.perf_counter()     # perfetto ts origin
@@ -288,15 +321,11 @@ def _jsonable(v):
 def stage_list(span: Span, base: float | None = None, depth: int = 0
                ) -> list:
     """Flatten a span subtree into flight-recorder stage rows:
-    ``{name, t0_ms (offset from the subtree root), dur_ms, depth
-    [, fused]}`` — enough to rebuild a timeline without the live
-    tracer."""
+    ``{name, t0_ms (offset from the subtree root), dur_ms, depth}`` —
+    enough to rebuild a timeline without the live tracer."""
     base = span.t0 if base is None else base
-    row = {"name": span.name, "t0_ms": round((span.t0 - base) * 1e3, 3),
-           "dur_ms": round(span.wall_s * 1e3, 3), "depth": depth}
-    if span.fused:
-        row["fused"] = True
-    out = [row]
+    out = [{"name": span.name, "t0_ms": round((span.t0 - base) * 1e3, 3),
+            "dur_ms": round(span.wall_s * 1e3, 3), "depth": depth}]
     for ch in span.children:
         out.extend(stage_list(ch, base, depth + 1))
     return out
@@ -367,16 +396,21 @@ _JIT_ENTRY_POINTS = (
     ("repro.core.dropout", "_bucket_corrections"),
 )
 
-# (label, id(fn)) -> fn: per-instance executables (CohortEngine's vmapped
-# cohort fns) registered at creation time
+# (label, id(fn)) -> weak reference to fn: per-instance executables
+# (CohortEngine's vmapped cohort fns) registered at creation time. Weak, so
+# that the probe neither keeps an engine's programs alive nor, through
+# their closures, the frozen base its spec holds; an entry leaves with the
+# function it names.
 _DYNAMIC_JITS: dict = {}
 
 
 def register_jit(label: str, fn):
     """Track a dynamically created jitted callable in the cache probe
-    (no-op for objects without ``_cache_size``)."""
+    (no-op for objects without ``_cache_size``) for as long as it lives."""
     if hasattr(fn, "_cache_size"):
-        _DYNAMIC_JITS[(label, id(fn))] = fn
+        k = (label, id(fn))
+        _DYNAMIC_JITS[k] = weakref.ref(
+            fn, lambda _, k=k: _DYNAMIC_JITS.pop(k, None))
     return fn
 
 
@@ -390,8 +424,10 @@ def jit_cache_sizes() -> dict:
         if fn is not None and hasattr(fn, "_cache_size"):
             out[f"{mod_name.rsplit('.', 1)[-1]}.{attr}"] = \
                 int(fn._cache_size())
-    for (label, _), fn in _DYNAMIC_JITS.items():
-        out[label] = out.get(label, 0) + int(fn._cache_size())
+    for (label, _), ref in list(_DYNAMIC_JITS.items()):
+        fn = ref()
+        if fn is not None:
+            out[label] = out.get(label, 0) + int(fn._cache_size())
     return out
 
 
@@ -490,8 +526,6 @@ def perfetto_from_flight(events: list, task_id: int) -> dict:
             continue
         for row in stages:
             args = {"round": ev.get("round"), "depth": row["depth"]}
-            if row.get("fused"):
-                args["fused"] = True
             out.append({"name": row["name"], "ph": "X", "pid": 0,
                         "tid": row["depth"],
                         "ts": cursor_us + row["t0_ms"] * 1e3,

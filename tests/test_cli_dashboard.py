@@ -218,7 +218,9 @@ def test_render_trace_transcript(tmp_path):
     assert "cohort=4 survivors=4" in out
     assert "route=single_dispatch" in out
     assert "aggregate" in out and "secure_agg" in out
-    assert "(fused)" in out            # dp/quantize/mask/vg_sum rows
+    # the chain's stages run in one program: its host span, no copies of
+    # its window under the fused stages' names
+    assert "cohort_interims" in out and "(fused)" not in out
     # unknown task / missing recorder degrade to messages, not crashes
     assert "no flight records" in render_trace(svc, 999)
     svc.flight = None

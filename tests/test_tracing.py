@@ -28,15 +28,72 @@ def _flatten(span):
 # span tracer
 # ---------------------------------------------------------------------------
 
-def test_null_tracer_is_default_and_free():
+def test_null_tracer_is_default_and_free(monkeypatch):
     assert not tracing.enabled()
     sp = tracing.span("anything", task=1)
+
+    def counted(*a, **k):
+        raise AssertionError("the null tracer counted bytes")
+
+    monkeypatch.setattr(tracing, "tree_bytes", counted)
     with sp as inner:
         assert inner is sp
         assert inner.set(x=1) is inner
-        assert inner.mark_fused("a", "b") is inner
+        assert inner.transfer(h2d=np.zeros(4), d2h=jnp.zeros(4)) is inner
+    assert sp.attrs == {}
     # shared singleton: no allocation per span site
     assert tracing.span("other") is sp
+
+
+def test_transfer_counts_host_and_device_bytes():
+    host = {"a": np.zeros((3, 4), np.float32), "b": np.zeros(5, np.int8)}
+    dev = {"a": jnp.zeros((2, 8), jnp.float32), "h": np.zeros(7)}
+    with tracing.use_tracer(tracing.Tracer()) as tr:
+        with tracing.span("move") as sp:
+            # only host leaves go h2d, only device leaves come d2h
+            sp.transfer(h2d=(host, dev), d2h=(host, dev))
+            sp.transfer(h2d=np.zeros(2, np.float32))
+    (root,) = tr.roots()
+    assert root.attrs == {"h2d_bytes": 48 + 5 + 56 + 8, "d2h_bytes": 64}
+
+
+def test_tracing_imports_without_jax():
+    import subprocess
+    import sys
+    code = ("import sys; sys.modules['jax'] = None\n"
+            "from repro import tracing\n"
+            "with tracing.use_tracer(tracing.Tracer()):\n"
+            "    with tracing.span('s'):\n"
+            "        pass\n"
+            "assert tracing.jit_cache_total() == 0\n")
+    out = subprocess.run([sys.executable, "-c", code],
+                         capture_output=True, text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+
+
+def test_profiler_mode_mirrors_spans(tmp_path):
+    """``Tracer(profiler=True)``: each span is also a profiler annotation
+    named ``florida.<name>``, nested as the spans are."""
+    from jax.profiler import ProfileData
+
+    tr = tracing.Tracer(profiler=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing.use_tracer(tr):
+            with tracing.span("outer"):
+                with tracing.span("inner"):
+                    jax.block_until_ready(jnp.ones(4) * 2)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = list(tmp_path.glob("**/*.xplane.pb"))
+    data = ProfileData.from_file(str(path))
+    events = {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+              for p in data.planes for ln in p.lines for e in ln.events
+              if e.name.startswith(tracing.PROFILER_PREFIX)}
+    assert set(events) == {"florida.outer", "florida.inner"}
+    (o0, o1), (i0, i1) = events["florida.outer"], events["florida.inner"]
+    assert o0 <= i0 <= i1 <= o1
+    assert [r.name for r in tr.roots()] == ["outer"]
 
 
 def test_span_nesting_and_attrs():
@@ -56,15 +113,40 @@ def test_span_nesting_and_attrs():
     assert outer.t0 <= a.t0 <= a.t1 <= b.t1 <= outer.t1
 
 
-def test_mark_fused_emits_shared_window_children():
-    with tracing.use_tracer(tracing.Tracer()) as tr:
-        with tracing.span("dispatch") as sp:
-            sp.mark_fused("dp", "quantize", "mask")
-    (root,) = tr.roots()
-    assert [c.name for c in root.children] == ["dp", "quantize", "mask"]
-    for c in root.children:
-        assert c.fused and c.attrs["fused"] is True
-        assert (c.t0, c.t1) == (root.t0, root.t1)
+@pytest.mark.parametrize("program", ["_cohort_interims",
+                                     "_wave_limb_state"])
+def test_chain_stages_are_named_scopes_on_the_device(program):
+    """The chain's four fused stages are ``jax.named_scope``s: each names
+    its operations in the compiled program's op metadata, where a
+    profiler trace of the device can find them."""
+    import re
+
+    from repro.core import dp as dp_mod
+    from repro.core import privacy_engine as pe
+    from repro.core import secure_agg as sa
+    from repro.core.virtual_groups import make_virtual_groups
+
+    cids = [f"c{i}" for i in range(8)]
+    buckets = pe.plan_buckets(make_virtual_groups(cids, 4, seed=0), cids)
+    seed = jnp.asarray([1, 2], jnp.uint32)
+    key = jax.random.PRNGKey(0)
+    cfg = dict(secure_cfg=sa.SecureAggConfig(),
+               dp_cfg=dp_mod.DPConfig(mechanism="local", clip_norm=0.5,
+                                      noise_multiplier=1.0))
+    if program == "_cohort_interims":
+        lowered = pe._cohort_interims.lower(
+            jnp.zeros((8, 32)), seed, key,
+            tuple(jnp.asarray(b.rows, jnp.int32) for b in buckets),
+            tuple(jnp.asarray(b.vg_ids, jnp.uint32) for b in buckets),
+            bucket_shapes=tuple((b.g, b.n_groups) for b in buckets),
+            n_shards=1, **cfg)
+    else:
+        lowered = pe._wave_limb_state.lower(
+            jnp.zeros((8, 32)), jnp.arange(8, dtype=jnp.uint32), seed, key,
+            jnp.asarray([0, 1], jnp.uint32), jnp.ones(2, bool), g=4, **cfg)
+    text = lowered.compile().as_text()
+    scopes = set(re.findall(rf'op_name="jit\({program}\)/(\w+)/', text))
+    assert {"dp", "quantize", "mask", "vg_sum"} <= scopes
 
 
 def test_thread_safety_separate_stacks():
@@ -129,8 +211,9 @@ def test_perfetto_export_structure(tmp_path):
     tr = tracing.Tracer()
     with tracing.use_tracer(tr):
         with tr.span("round", task=1):
-            with tr.span("aggregate") as sp:
-                sp.mark_fused("dp")
+            with tr.span("aggregate"):
+                with tr.span("dp"):
+                    pass
     path = tr.export_perfetto(str(tmp_path / "t.json"))
     doc = json.load(open(path))
     assert doc["displayTimeUnit"] == "ms"
@@ -141,8 +224,7 @@ def test_perfetto_export_structure(tmp_path):
         assert "cpu_ms" in e["args"]
     metas = [e for e in doc["traceEvents"] if e.get("ph") == "M"]
     assert any(m["name"] == "process_name" for m in metas)
-    # fused children are synthesized on exit, not pushed: 2 real spans
-    assert doc["otherData"]["n_spans"] == 2
+    assert doc["otherData"]["n_spans"] == 3
 
 
 def test_stage_list_offsets_and_depth():
@@ -225,6 +307,42 @@ def test_register_jit_counts_executables():
         assert tracing.jit_cache_sizes()["test_probe.double"] == base + 2
     finally:
         tracing._DYNAMIC_JITS.pop(("test_probe.double", id(fn)), None)
+
+
+def test_register_jit_does_not_keep_engines_alive():
+    """The probe holds an engine's programs weakly: once the engine is
+    gone its frozen base is freed, and the probe still counts the shared
+    entry points."""
+    import gc
+    import weakref
+
+    from repro.core import privacy_engine as pe
+    from repro.core.cohort_engine import CohortEngine, LocalTrainSpec
+    from repro.optim.adamw import adamw
+
+    def loss(p, b, frozen):
+        return jnp.mean((b["x"] @ (frozen["w"] + p["d"])) ** 2)
+
+    frozen = {"w": jnp.ones((4, 2), jnp.float32)}
+    engine = CohortEngine(
+        LocalTrainSpec(loss_fn=loss, optimizer=adamw(0.1), local_steps=1,
+                       frozen=frozen),
+        lambda cid, r: {"x": np.ones((1, 3, 4), np.float32)})
+    params = {"d": jnp.zeros((4, 2), jnp.float32)}
+    jax.block_until_ready(engine.run_cohort_stacked(params, ["a", "b"], 0))
+    jax.block_until_ready(pe.ravel_rows({"w": jnp.zeros((2, 3))}))
+    sizes = tracing.jit_cache_sizes()
+    assert sizes["cohort_engine.shared"] >= 1
+    assert sizes["privacy_engine.ravel_rows"] >= 1
+    gone = weakref.ref(frozen["w"])
+    del engine, frozen
+    gc.collect()
+    assert gone() is None
+    after = tracing.jit_cache_sizes()
+    assert after["privacy_engine.ravel_rows"] == \
+        sizes["privacy_engine.ravel_rows"]
+    assert after.get("cohort_engine.shared", 0) \
+        <= sizes["cohort_engine.shared"] - 1
 
 
 def test_async_pad_classes_no_recompile_second_batch():
@@ -396,7 +514,9 @@ def test_store_save_header_defaults(tmp_path):
 # bit-identity: tracing must never touch the math
 # ---------------------------------------------------------------------------
 
-def test_traced_round_bit_identical_to_untraced():
+@pytest.mark.parametrize("profiler", [False, True],
+                         ids=["collect", "profiler"])
+def test_traced_round_bit_identical_to_untraced(profiler):
     from repro.core import dp as dp_mod
     from repro.core import secure_agg as sa
     from repro.core.orchestrator import run_sync_round_stacked
@@ -420,14 +540,14 @@ def test_traced_round_bit_identical_to_untraced():
         return np.asarray(out["w"]).view(np.uint32).tobytes(), info
 
     plain, _ = run()
-    with tracing.use_tracer(tracing.Tracer()) as tr:
+    with tracing.use_tracer(tracing.Tracer(profiler=profiler)) as tr:
         traced, info = run()
     assert traced == plain
     assert info.stage2_route == "single_dispatch"
-    # the full fused stage tree was recorded alongside identical bits
+    # the chain's stage tree was recorded alongside identical bits
     names = {s.name for r in tr.roots() for s in _flatten(r)}
-    assert {"secure_agg", "cohort_interims", "dp", "quantize", "mask",
-            "vg_sum", "limb_combine", "server_update"} <= names
+    assert {"vg_plan", "secure_agg", "ravel_rows", "cohort_interims",
+            "limb_combine", "server_update"} <= names
 
 
 # ---------------------------------------------------------------------------
