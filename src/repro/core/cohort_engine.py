@@ -172,6 +172,18 @@ def stack_trees(trees: list):
                         *trees)
 
 
+def _free_device_bytes(devices) -> int | None:
+    """``bytes_limit - bytes_in_use`` of the fullest of ``devices``, from
+    ``memory_stats()``; None where a device reports no memory (the CPU)."""
+    free = []
+    for d in devices:
+        stats = d.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            return None
+        free.append(stats["bytes_limit"] - stats.get("bytes_in_use", 0))
+    return min(free)
+
+
 def unstack_tree(tree, n: int):
     """pytree with leading n axis -> [pytree, ...] of length n.
 
@@ -200,10 +212,14 @@ class CohortEngine:
         # wave_size: stream cohorts LARGER than this through fixed-width
         # compiled waves (the last wave pads by repeating its final member
         # and the pad rows are dropped) — one compiled shape serves any
-        # cohort size, bounding both compile count and device memory at
-        # 10^4-10^5-client cohorts. Per-client outputs are bit-identical
-        # to the single-dispatch path (vmap width does not change per-row
-        # float bits — the serial/vmap parity property). None/0 = off.
+        # cohort size, bounding the compile count and the training's
+        # device memory to one wave. The waves' deltas stay on the device
+        # when the cohort's rows fit there beside a wave, else they go to
+        # the host wave by wave, so that at 10^4-10^5-client cohorts the
+        # device holds one wave (``_run_waves``). Per-client outputs are
+        # bit-identical to the single-dispatch path (vmap width does not
+        # change per-row float bits — the serial/vmap parity property).
+        # None/0 = off.
         self.spec = spec
         self.batch_fn = batch_fn
         self.template = template_params
@@ -212,6 +228,7 @@ class CohortEngine:
         self.wave_size = wave_size
         self._local = jax.jit(make_local_update(spec))
         self._fns: dict = {}
+        self._wave_bytes: dict = {}   # wave shapes -> (row, footprint)
 
     def _cohort_fn(self, personalized: bool):
         key = bool(personalized)
@@ -244,10 +261,10 @@ class CohortEngine:
         round trip that ``run_cohort`` pays."""
         w = self.wave_size
         with tracing.span("local_train", n=len(client_ids),
-                          round=round_idx):
+                          round=round_idx) as sp:
             if w and len(client_ids) > w:
                 return self._run_waves(params, list(client_ids),
-                                       round_idx, w)
+                                       round_idx, w, sp)
             batches = self._make_batches(params, client_ids,
                                          [round_idx] * len(client_ids))
             if self.mesh is not None:
@@ -267,16 +284,25 @@ class CohortEngine:
             sp.transfer(h2d=(params, batches))
         return batches
 
-    def _run_waves(self, params, client_ids, round_idx: int, w: int):
+    def _run_waves(self, params, client_ids, round_idx: int, w: int,
+                   span):
         """Stream an oversized cohort through fixed-width ``w``-client
-        waves of the shared-params executable. Each wave's outputs are
-        pulled to host before the next dispatches, so device memory holds
-        ONE wave regardless of cohort size; the short last wave pads by
-        repeating its final member (pad rows dropped on host), so a single
-        compiled shape serves every cohort size."""
+        waves of the shared-params executable; the short last wave pads by
+        repeating its final member (pad rows dropped), so a single compiled
+        shape serves every cohort size.
+
+        Where the waves' deltas gather is decided once, before the first
+        wave dispatches (:meth:`_rows_fit_on_device`), and tagged on the
+        ``local_train`` span as ``rows``. ``"device"``: each wave's outputs
+        stay on the device and are stacked there, with no sync between
+        waves, and the caller gets device arrays that the privacy chain
+        ravels in place. ``"host"``: each wave's outputs are pulled to the
+        host before the next dispatches and stacked there, so the device
+        holds ONE wave whatever the cohort's size."""
         if self.mesh is not None:
             self._check_divisible(w)
         fn = self._cohort_fn(False)
+        on_device = None
         delta_parts, loss_parts, n_samples = [], [], None
         for s in range(0, len(client_ids), w):
             chunk = client_ids[s:s + w]
@@ -287,9 +313,20 @@ class CohortEngine:
                               n_real=n_real):
                 batches = self._make_batches(params, chunk,
                                              [round_idx] * w)
+                if on_device is None:
+                    on_device = self._rows_fit_on_device(
+                        fn, params, batches, len(client_ids))
+                    span.set(rows="device" if on_device else "host")
                 deltas, losses = fn(params, batches, self.spec.frozen)
                 if n_samples is None:
                     n_samples = self._n_samples(batches, stacked=True)
+                if on_device:
+                    if n_real < w:
+                        deltas = jax.tree.map(lambda a: a[:n_real], deltas)
+                        losses = losses[:n_real]
+                    delta_parts.append(deltas)
+                    loss_parts.append(losses)
+                    continue
                 with tracing.span("wave_to_host") as sp:
                     sp.transfer(d2h=(deltas, losses))
                     host = jax.tree.map(np.asarray, deltas)
@@ -297,11 +334,47 @@ class CohortEngine:
                                                     host))
                     loss_parts.append(np.asarray(losses)[:n_real])
         with tracing.span("stack_waves") as sp:
+            if on_device:
+                # the parts go with this frame, as soon as the stack exists
+                return (jax.tree.map(lambda *xs: jnp.concatenate(xs),
+                                     *delta_parts),
+                        jnp.concatenate(loss_parts), n_samples)
             stacked = jax.tree.map(lambda *xs: np.concatenate(xs, axis=0),
                                    *delta_parts)
             losses = np.concatenate(loss_parts)
             sp.transfer(h2d=losses)
             return stacked, jnp.asarray(losses), n_samples
+
+    def _rows_fit_on_device(self, fn, params, batches, n_rows: int) -> bool:
+        """Whether a waved cohort's ``n_rows`` deltas may gather on the
+        device: twice their bytes (the stack the caller holds, and
+        ``ravel_rows``' flat copy of it that the privacy chain makes) plus
+        one wave's compiled footprint (arguments, outputs, temporaries)
+        must fit in what the device has free now. A row's bytes and the
+        footprint come from the wave executable as compiled for these
+        shapes (the one the waves then run), read once per shape. A device
+        that reports no memory keeps the rows on the host."""
+        devices = (self.mesh.local_devices if self.mesh is not None
+                   else jax.local_devices()[:1])
+        free = _free_device_bytes(devices)
+        if free is None:
+            return False
+        leaves = jax.tree.leaves((params, batches))
+        key = (jax.tree.structure((params, batches)),
+               tuple((a.shape, str(a.dtype)) for a in leaves))
+        if key not in self._wave_bytes:
+            compiled = fn.lower(params, batches, self.spec.frozen).compile()
+            mem = compiled.memory_analysis()
+            w = jax.tree.leaves(batches)[0].shape[0]
+            row = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(compiled.out_info[0])) // w
+            self._wave_bytes[key] = (row, mem.argument_size_in_bytes
+                                     + mem.output_size_in_bytes
+                                     - mem.alias_size_in_bytes
+                                     + mem.temp_size_in_bytes)
+        row, footprint = self._wave_bytes[key]
+        shards = self.mesh.shape[self.axis] if self.mesh is not None else 1
+        return 2 * n_rows * row // shards + footprint <= free
 
     def run_cohort_personalized(self, params_list, client_ids, round_idxs):
         """Per-client params (clustered FL branches, async mixed-version

@@ -274,24 +274,114 @@ def test_privacy_wave_aggregate_bit_identical(n, vg, wave, mech):
     np.testing.assert_array_equal(np.asarray(one), np.asarray(waved))
 
 
-def test_cohort_engine_wave_matches_single_dispatch():
-    """Waved local training (10 clients through 4-wide waves) returns the
-    same per-client deltas and losses as one full-cohort dispatch."""
+def _spam_world():
     from benchmarks.common import SpamWorld
+    return SpamWorld(vocab=128, d_model=16, seq_len=8, n_train=400,
+                     n_splits=5, batch_size=2, d_ff=32, head_dim=8)
+
+
+def _force_route(monkeypatch, route):
+    """Make the device report as much free memory as the route needs: all
+    it could want (the rows fit), or none (they do not)."""
+    from repro.core import cohort_engine
+    free = 10**15 if route == "device" else 0
+    monkeypatch.setattr(cohort_engine, "_free_device_bytes",
+                        lambda devices: free)
+
+
+def _spans(sp):
+    yield sp
+    for ch in sp.children:
+        yield from _spans(ch)
+
+
+@pytest.mark.parametrize("route,sharded", [("device", False),
+                                           ("host", False),
+                                           ("device", True)],
+                         ids=["device", "host", "device-mesh"])
+def test_cohort_engine_wave_matches_single_dispatch(route, sharded,
+                                                    monkeypatch):
+    """Waved local training (10 clients through 4-wide waves, the last one
+    padded) returns the same per-client deltas and losses as one
+    full-cohort dispatch, whether the rows gather on the device or on the
+    host, and through the shard_map path on a one-device mesh; only the
+    host route pulls the deltas down."""
+    import jax
+    from jax.sharding import Mesh
+    from repro import tracing
     from repro.core.cohort_engine import CohortEngine
-    world = SpamWorld(vocab=128, d_model=16, seq_len=8, n_train=400,
-                      n_splits=5, batch_size=2, d_ff=32, head_dim=8)
+    world = _spam_world()
     engine = world.make_engine(local_steps=2, batch_size=2)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",)) if sharded else None
     waved = CohortEngine(engine.spec, engine.batch_fn,
-                         template_params=world.model0, wave_size=4)
+                         template_params=world.model0, mesh=mesh,
+                         wave_size=4)
     cids = [f"client-{i:04d}" for i in range(10)]
     d1, l1, n1 = engine.run_cohort_stacked(world.model0, cids, round_idx=0)
-    d2, l2, n2 = waved.run_cohort_stacked(world.model0, cids, round_idx=0)
+    _force_route(monkeypatch, route)
+    with tracing.use_tracer(tracing.Tracer()) as tr:
+        d2, l2, n2 = waved.run_cohort_stacked(world.model0, cids,
+                                              round_idx=0)
+    (root,) = tr.find_roots("local_train")
+    assert root.attrs["rows"] == route
+    names = [sp.name for sp in _spans(root)]
+    assert names.count("train_wave") == 3 and "stack_waves" in names
+    d2h = sum(sp.attrs.get("d2h_bytes", 0) for sp in _spans(root))
+    if route == "device":
+        assert "wave_to_host" not in names and d2h == 0
+        assert all(isinstance(a, jax.Array) for a in jax.tree.leaves(d2))
+    else:
+        assert names.count("wave_to_host") == 3 and d2h > 0
+        assert all(isinstance(a, np.ndarray) for a in jax.tree.leaves(d2))
     assert n1 == n2
     np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2))
-    import jax
-    for a, b in zip(jax.tree.leaves(d1), jax.tree.leaves(d2)):
+    for a, b in zip(jax.tree.leaves(d1), jax.tree.leaves(d2), strict=True):
+        assert a.shape == b.shape
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_churn_round_from_waved_engine_same_model_on_both_routes(
+        monkeypatch):
+    """A churn round through ``ManagementService.submit_cohort``, the
+    survivors' deltas from a waved engine: the model comes out bit for bit
+    the same whether the rows gathered on the device or on the host, and
+    as from one unwaved dispatch."""
+    import jax
+    from repro.core.cohort_engine import CohortEngine
+    world = _spam_world()
+    base = world.make_engine(local_steps=2, batch_size=2)
+
+    def run(route):
+        engine = base if route is None else CohortEngine(
+            base.spec, base.batch_fn, template_params=world.model0,
+            wave_size=4)
+        if route is not None:
+            _force_route(monkeypatch, route)
+        svc = ManagementService(seed=0)
+        cfg = TaskConfig("churn", "app", "wf", clients_per_round=12,
+                         n_rounds=1, vg_size=4, selection=_CRIT)
+        tid = svc.create_task(cfg, world.model0)
+        svc.register_fleet(tid, PopulationArrays.sample(64, seed=1))
+        r, cohort = svc.begin_round(tid)
+        assert len(cohort) == 12
+        survivors = [c for j, c in enumerate(cohort) if j not in (2, 7)]
+        stacked, losses, n = engine.run_cohort_stacked(world.model0,
+                                                       survivors, r)
+        on_host = isinstance(jax.tree.leaves(stacked)[0], np.ndarray)
+        assert on_host == (route == "host")
+        for cid in (cohort[2], cohort[7]):
+            svc.report_dropout(tid, cid)
+        assert svc.submit_cohort(tid, survivors, stacked, n,
+                                 [{"loss": float(x)}
+                                  for x in np.asarray(losses)])
+        return jax.tree.map(np.asarray, svc.get_task(tid).model)
+
+    want = run(None)
+    for route in ("device", "host"):
+        got = run(route)
+        for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got),
+                        strict=True):
+            np.testing.assert_array_equal(a, b)
 
 
 def test_wave_round_through_management_service():
