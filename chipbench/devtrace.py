@@ -1,13 +1,16 @@
 """From a profiler trace to the numbers the per-layer metrics read.
 
 ``load`` reads the ``.xplane.pb`` that ``jax.profiler`` writes and keeps
-three lists of ``[name, start_ns, duration_ns]`` on one clock:
+four lists of ``[name, start_ns, duration_ns]`` on one clock:
 
 - ``ops``: device operations (the TPU plane's ``XLA Ops`` line);
 - ``modules``: device executions of whole compiled programs (its ``XLA
   Modules`` line), named as the program names them (``jit_ravel_rows``);
 - ``spans``: the benchmark's host spans (``chipbench.<name>``,
-  ``jax.profiler.TraceAnnotation``).
+  ``jax.profiler.TraceAnnotation``);
+- ``program_spans``: the program's own spans, which it mirrors into the
+  profiler as ``florida.<name>`` under ``repro.tracing.Tracer(profiler=
+  True)``, without the prefix (``progtrace`` reads them).
 
 The rest are pure functions of that summary, so a small recorded trace
 checks them (``testdata/``).
@@ -21,6 +24,7 @@ import re
 from collections import defaultdict
 
 SPAN_PREFIX = "chipbench."
+PROGRAM_PREFIX = "florida."
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 
 
@@ -39,7 +43,7 @@ def load(path: str) -> dict:
     opener = gzip.open if path.endswith(".gz") else open
     with opener(path, "rb") as f:
         data = ProfileData.from_serialized_xspace(f.read())
-    out = {"ops": [], "modules": [], "spans": []}
+    out = {"ops": [], "modules": [], "spans": [], "program_spans": []}
     device_done = False
     for plane in data.planes:
         if DEVICE_PLANE.match(plane.name) and not device_done:
@@ -52,9 +56,14 @@ def load(path: str) -> dict:
                                      int(e.duration_ns)] for e in line.events)
         elif not DEVICE_PLANE.match(plane.name):
             for line in plane.lines:
-                out["spans"].extend(
-                    [e.name, int(e.start_ns), int(e.duration_ns)]
-                    for e in line.events if e.name.startswith(SPAN_PREFIX))
+                for e in line.events:
+                    if e.name.startswith(SPAN_PREFIX):
+                        out["spans"].append([e.name, int(e.start_ns),
+                                             int(e.duration_ns)])
+                    elif e.name.startswith(PROGRAM_PREFIX):
+                        out["program_spans"].append(
+                            [e.name[len(PROGRAM_PREFIX):], int(e.start_ns),
+                             int(e.duration_ns)])
     for k in out:
         out[k].sort(key=lambda e: e[1])
     return out

@@ -2,10 +2,10 @@
 
 Under ``repro.tracing.Tracer(profiler=True)`` the program mirrors each of
 its spans (``begin_round``, ``snapshot``, ``local_train``, ``secure_agg``,
-...) into the profiler as a host event named ``florida.<span>``. ``load``
-keeps them beside ``devtrace.load``'s summary, under ``program_spans``, as
-``[name, start_ns, duration_ns]`` without the prefix; ``idle_in_spans``
-reads how long the device sat idle while the host was inside them.
+...) into the profiler as a host event named ``florida.<span>``.
+``devtrace.load`` keeps them under ``program_spans``, as ``[name,
+start_ns, duration_ns]`` without the prefix; ``idle_in_spans`` reads how
+long the device sat idle while the host was inside them.
 
 A trace of a program that mirrors nothing has no such events: every
 reading is then 0 and the metrics that read them report nothing.
@@ -13,28 +13,9 @@ reading is then 0 and the metrics that read them report nothing.
 from __future__ import annotations
 
 import bisect
-import gzip
 from collections import defaultdict
 
 from chipbench import devtrace
-
-PREFIX = "florida."
-
-
-def load(path: str) -> dict:
-    """``devtrace.load(path)`` plus ``program_spans``."""
-    from jax.profiler import ProfileData
-    summary = devtrace.load(path)
-    opener = gzip.open if path.endswith(".gz") else open
-    with opener(path, "rb") as f:
-        data = ProfileData.from_serialized_xspace(f.read())
-    spans = [[e.name[len(PREFIX):], int(e.start_ns), int(e.duration_ns)]
-             for plane in data.planes
-             if not devtrace.DEVICE_PLANE.match(plane.name)
-             for line in plane.lines for e in line.events
-             if e.name.startswith(PREFIX)]
-    summary["program_spans"] = sorted(spans, key=lambda e: e[1])
-    return summary
 
 
 def idle(summary: dict, lo: int, hi: int) -> list:

@@ -9,8 +9,9 @@ cohort engine, and drives the first three rounds through the window's own
 call; those rounds compile every program the window uses and are what the
 reference is compared with. The window then runs rounds, closed loop, for
 ``--seconds`` (``--trace 1``: the workload's ``trace_rounds`` rounds under
-the profiler). After the window the program's state is freed and the
-reference replays the three rounds.
+the profiler, with the program's tracer mirroring its spans into it).
+After the window the program's state is freed and the reference replays
+the three rounds.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
@@ -146,6 +147,28 @@ def run_window(cell, seconds: float, max_rounds: int | None = None):
     return time.perf_counter() - start, durations
 
 
+def trace_window(cell, seconds: float, rounds: int, trace_dir: str):
+    """``run_window`` under the profiler, with the program's tracer
+    mirroring its spans into the trace (``Tracer(profiler=True)``).
+    -> (``devtrace.load`` summary, the program's tracer, window seconds,
+    per-round seconds). The trace stays in ``trace_dir``."""
+    import jax
+
+    from chipbench import devtrace
+    from repro import tracing
+
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    tracer = tracing.Tracer(profiler=True)
+    jax.profiler.start_trace(trace_dir)
+    try:
+        with tracing.use_tracer(tracer):
+            window_s, durations = run_window(cell, seconds, rounds)
+    finally:
+        jax.profiler.stop_trace()
+    summary = devtrace.load(devtrace.find_xplane(trace_dir))
+    return summary, tracer, window_s, durations
+
+
 def end_to_end(bench, name, setup_s, window_s, durations) -> dict:
     known = {
         "setup_s": lambda: setup_s,
@@ -162,8 +185,9 @@ def end_to_end(bench, name, setup_s, window_s, durations) -> dict:
     return out
 
 
-def per_layer(bench, name, cell, summary, rounds, memory, peaks) -> tuple:
-    from chipbench import devtrace, flops
+def per_layer(bench, name, cell, summary, tracer, rounds, memory,
+              peaks) -> tuple:
+    from chipbench import devtrace, flops, trace_program
     task = cell.workload["task"]
     submitting = int(task["clients_per_round"]) \
         - int(task.get("drop_per_round", 0))
@@ -180,6 +204,7 @@ def per_layer(bench, name, cell, summary, rounds, memory, peaks) -> tuple:
                                                      cell.workload),
         "chain_bytes": flops.chain_bytes(submitting,
                                          int(cell.meta["params"])),
+        **trace_program.program_ctx(summary, tracer, rounds),
     }
     out = {}
     for m in metrics_for(bench["per_layer"], name):
@@ -245,18 +270,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, bench: dict,
     events = CompileEvents()
     events.on = True
 
-    summary = None
     if trace:
-        from chipbench import devtrace
         trace_dir = os.path.join(OUT_DIR, name, "trace")
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        jax.profiler.start_trace(trace_dir)
-        try:
-            window_s, durations = run_window(
-                cell, seconds, int(cell.workload["trace_rounds"]))
-        finally:
-            jax.profiler.stop_trace()
-        summary = devtrace.load(devtrace.find_xplane(trace_dir))
+        summary, tracer, window_s, durations = trace_window(
+            cell, seconds, int(cell.workload["trace_rounds"]), trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
     else:
         window_s, durations = run_window(cell, seconds)
@@ -268,7 +285,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, bench: dict,
 
     if trace:
         metrics, timing, breakdown = per_layer(
-            bench, name, cell, summary, len(durations), memory,
+            bench, name, cell, summary, tracer, len(durations), memory,
             flops.peaks(device["kind"]) if device["platform"] == "tpu"
             else None)
         device.update(timing)

@@ -12,9 +12,22 @@ A kind's file holds all that the harness needs of that kind of task:
 - ``reference_client(recipe, model, dtype, frozen) -> f(state, batches)``:
   the plain reference of one client's local round, returning ``(delta,
   mean loss, first gradient)``;
-- ``sample_flops(model, data) -> int``: model FLOPs of one training sample.
+- ``sample_flops(model, data) -> int``: model FLOPs of one training sample;
+- ``toy(config, workload) -> (config, workload)``: the CPU tests' shrink of
+  a cell of this kind (toy widths, few clients), made in place on copies
+  of the two files' contents.
 
-A later change adds a kind by adding its file.
+A configuration (``configs/<name>.json``) names its kind in ``fl.task``.
+Its top-level keys hold the published configuration as run, its
+``published_widths`` map each width of its ``model`` block to the
+published key it copies (``"d_model": "hidden_size"``), and a key cut to
+fit the chip holds the cut value, its published value under
+``published`` (``"published": {"num_hidden_layers": 28}``), and is named
+in the configuration's ``reduced`` in ``BENCHMARK.json``. Only a count of
+layers, of experts held, or the vocabulary may be cut.
+
+A later change adds a kind by adding its file, and a configuration and a
+cell by adding theirs and their entries in ``BENCHMARK.json``.
 """
 import importlib
 import os

@@ -69,6 +69,24 @@ def build(cfg, config: dict, workload: dict, key):
     return spec, model0, None
 
 
+def toy(config: dict, workload: dict) -> tuple:
+    """The CPU tests' shrink of a ``classify`` cell, in place: toy widths,
+    short rows, at most 3,000 clients and 16 a round, waves of 4 (where
+    the cell waves) and 2 dropped (where it drops). Returns ``(config,
+    workload)``."""
+    task = workload["task"]
+    config["model"].update(vocab_size=512, d_model=32, head_dim=16, d_ff=64,
+                           max_positions=16)
+    workload["data"].update(seq_len=16, n_train=2000, local_steps=2, batch=4)
+    task.update(clients=min(task["clients"], 3000),
+                clients_per_round=min(task["clients_per_round"], 16))
+    if task["wave_size"]:
+        task.update(wave_size=4, wave_clients=task["clients_per_round"])
+    if task.get("drop_per_round"):
+        task.update(drop_per_round=2)
+    return config, workload
+
+
 # --------------------------------------------------------------------------
 # reference
 # --------------------------------------------------------------------------

@@ -70,6 +70,18 @@ def build(cfg, config: dict, workload: dict, key):
     return spec, adapters, base
 
 
+def toy(config: dict, workload: dict) -> tuple:
+    """The CPU tests' shrink of a ``lora_lm`` cell, in place: one encoder
+    and one decoder layer at toy widths, 32 frames and 16 tokens, 16
+    clients and 4 a round. Returns ``(config, workload)``."""
+    config["model"].update(num_layers=1, num_encoder_layers=1, d_model=64,
+                           num_heads=2, num_kv_heads=2, head_dim=32,
+                           d_ff=128, vocab_size=512)
+    workload["data"].update(enc_frames=32, dec_tokens=16, frame_pool=8)
+    workload["task"].update(clients=16, clients_per_round=4)
+    return config, workload
+
+
 # --------------------------------------------------------------------------
 # reference
 # --------------------------------------------------------------------------

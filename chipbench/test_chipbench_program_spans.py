@@ -1,8 +1,9 @@
 """The program's own spans on the device trace's clock (``progtrace``,
-``trace_program``): the idle reduction on hand-made summaries; a toy
-cell's rounds traced on the CPU with the program mirroring its spans into
-the profiler; and a small trace recorded on a TPU v5e with program spans
-(``testdata/program_v5e.*``, written by ``record_program_testdata.py``)."""
+``trace_program``, ``run.trace_window``): the idle reduction on hand-made
+summaries; a toy cell's rounds traced on the CPU with the program
+mirroring its spans into the profiler; and a small trace recorded on a
+TPU v5e with program spans (``testdata/program_v5e.*``, written by
+``record_program_testdata.py``)."""
 import json
 import os
 
@@ -87,8 +88,8 @@ def traced(request, tmp_path_factory):
     config, workload = toy_cell(name)
     cell = cells.build(name, SEED, config=config, workload=workload)
     cell.run_round()                                   # compiles
-    summary, tracer, _ = trace_program.trace_window(
-        cell, ROUNDS, str(tmp_path_factory.mktemp("trace")))
+    summary, tracer, _, _ = run.trace_window(
+        cell, float("inf"), ROUNDS, str(tmp_path_factory.mktemp("trace")))
     return name, cell, summary, tracer
 
 
@@ -162,8 +163,8 @@ def test_null_tracer_counts_no_bytes(traced, monkeypatch):
 def recorded():
     with open(os.path.join(TESTDATA, "program_v5e.json")) as f:
         meta = json.load(f)
-    return meta, progtrace.load(os.path.join(TESTDATA,
-                                             "program_v5e.xplane.pb.gz"))
+    return meta, devtrace.load(os.path.join(TESTDATA,
+                                            "program_v5e.xplane.pb.gz"))
 
 
 def test_recorded_trace_holds_program_spans_in_benchmark_spans(recorded):

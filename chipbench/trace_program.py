@@ -50,27 +50,6 @@ CHAIN_PROGRAMS = ("_cohort_interims", "_cohort_interims_churn",
                   "_wave_limb_state")
 
 
-def trace_window(cell, rounds: int, trace_dir: str):
-    """``rounds`` rounds of ``cell`` under the profiler, with the program's
-    spans mirrored into it. -> (summary with ``program_spans``, the
-    program's tracer, per-round seconds)."""
-    import jax
-
-    from chipbench import devtrace, progtrace, run
-    from repro import tracing
-
-    shutil.rmtree(trace_dir, ignore_errors=True)
-    tracer = tracing.Tracer(profiler=True)
-    jax.profiler.start_trace(trace_dir)
-    try:
-        with tracing.use_tracer(tracer):
-            _, durations = run.run_window(cell, float("inf"), rounds)
-    finally:
-        jax.profiler.stop_trace()
-    summary = progtrace.load(devtrace.find_xplane(trace_dir))
-    return summary, tracer, durations
-
-
 def walk(spans):
     for sp in spans:
         yield sp
@@ -78,33 +57,50 @@ def walk(spans):
 
 
 def span_totals(tracer) -> dict:
-    """{span name: {host_s, calls, h2d_bytes, d2h_bytes}} over every span
-    the tracer holds."""
-    tot = defaultdict(lambda: {"host_s": 0.0, "calls": 0, "h2d_bytes": 0,
-                               "d2h_bytes": 0})
+    """{span name: {host_s, calls, h2d_bytes, d2h_bytes, and every other
+    numeric attribute}} summed over every span the tracer holds. The sum
+    of an identifier (``task``, ``round``) means nothing; a count (bytes,
+    rows, clients) sums to the window's."""
+    tot = defaultdict(lambda: defaultdict(int, host_s=0.0, calls=0,
+                                          h2d_bytes=0, d2h_bytes=0))
     for sp in walk(tracer.roots()):
         t = tot[sp.name]
         t["host_s"] += sp.wall_s
         t["calls"] += 1
-        for k in ("h2d_bytes", "d2h_bytes"):
-            t[k] += int(sp.attrs.get(k, 0))
-    return dict(tot)
+        for k, v in sp.attrs.items():
+            if isinstance(v, (int, float)) and not isinstance(v, bool):
+                t[k] += v
+    return {n: dict(t) for n, t in tot.items()}
+
+
+def counts(tracer) -> dict:
+    """{attribute: its sum over every span the tracer holds}: the
+    program's counters over the window, by name."""
+    out = defaultdict(int)
+    for t in span_totals(tracer).values():
+        for k, v in t.items():
+            if k not in ("host_s", "calls"):
+                out[k] += v
+    return dict(out)
 
 
 def transfer_bytes(tracer) -> int:
-    return sum(t["h2d_bytes"] + t["d2h_bytes"]
-               for t in span_totals(tracer).values())
+    c = counts(tracer)
+    return c.get("h2d_bytes", 0) + c.get("d2h_bytes", 0)
 
 
 def program_ctx(summary: dict, tracer, rounds: int) -> dict:
     """What the program-span metrics read: the window's rounds, the
-    device-idle ns inside named program spans, the bytes counted."""
+    device-idle ns inside named program spans (``program_idle_ns``), the
+    bytes counted (``transfer_bytes``) and any counter of the program's
+    spans by name (``program_counts``: None where no span carries it)."""
     from chipbench import devtrace, progtrace
     lo, hi = devtrace.window(summary)
     return {"rounds": rounds,
             "program_idle_ns": lambda names: progtrace.idle_in_spans(
                 summary, names, lo, hi),
-            "transfer_bytes": transfer_bytes(tracer)}
+            "transfer_bytes": transfer_bytes(tracer),
+            "program_counts": counts(tracer).get}
 
 
 def coverage(summary: dict, rounds: int) -> dict:
@@ -234,7 +230,8 @@ def main(argv=None) -> int:
     rounds = int(cell.workload["trace_rounds"])
     trace_dir = os.path.join(run.OUT_DIR, args.workload, "program_trace")
     with recording_chain_calls() as calls:
-        summary, tracer, durations = trace_window(cell, rounds, trace_dir)
+        summary, tracer, _, durations = run.trace_window(
+            cell, float("inf"), rounds, trace_dir)
     named = ops_naming_a_stage(devtrace.find_xplane(trace_dir))
     shutil.rmtree(trace_dir, ignore_errors=True)
     lo, hi = devtrace.window(summary)
