@@ -108,33 +108,40 @@ def plan_buckets(plan, client_order) -> tuple:
     return tuple(buckets)
 
 
-def _interims_body(flat, round_seed, key, rows_t, vgs_t, alive,
+def _interims_body(flat, row_ids, round_seed, key, rows_t, vgs_t, live_t,
                    bucket_shapes, secure_cfg, dp_cfg):
-    """Shared trace body: (n, size) f32 stacked updates -> (G, size)
-    uint32 per-VG wrapping sums, bucket order.
+    """Shared trace body: (k, size) f32 update rows -> (G, size) uint32
+    per-VG wrapping sums, bucket order.
 
-    ``alive``: None (every row submits — the churn-free path compiles
-    with no extra ops) or a traced (n,) bool row mask: each SURVIVOR's
-    payload still carries its FULL net mask (clients masked before drops
-    were known), dropped rows are zeroed before the group sums, and the
-    caller repairs the non-cancelling residual via
-    ``dropout.recover_interims``. DP/quantize run on every row either
-    way, so a survivor's code — key-folded at its FULL-cohort row — is
-    bit-identical whether or not anyone else dropped."""
+    ``row_ids``: None (row ``j`` of ``flat`` is cohort row ``j`` — the
+    churn-free path compiles with no extra ops) or a traced (k,) uint32
+    cohort row per ``flat`` row: the DP key folds there, so a survivor's
+    code is bit-identical however many rows ``flat`` holds. ``rows_t``
+    index ``flat``'s rows. ``live_t``: None (every member submits) or one
+    traced (m*g,) bool mask per bucket, False at a dropped member's slot:
+    each SURVIVOR's payload still carries its FULL net mask (clients
+    masked before drops were known), a dropped slot (which may read any
+    row) is zeroed before the group sums, and the caller repairs the
+    non-cancelling residual via ``dropout.recover_interims``."""
     n = flat.shape[0]
     # per-client DP, vmapped over the client axis; key folding follows the
-    # row order (== sorted-cid order in the orchestrator), matching the
-    # serial reference's fold_in(key, j) exactly.
-    flat = _dp_rows(flat, jnp.arange(n, dtype=jnp.uint32), key, dp_cfg)
+    # cohort row order (== sorted-cid order in the orchestrator), matching
+    # the serial reference's fold_in(key, j) exactly.
+    if row_ids is None:
+        row_ids = jnp.arange(n, dtype=jnp.uint32)
+    flat = _dp_rows(flat, row_ids, key, dp_cfg)
     with jax.named_scope("quantize"):
-        qs = quantize(flat, secure_cfg.clip, secure_cfg.bits)  # (n, size)
+        qs = quantize(flat, secure_cfg.clip, secure_cfg.bits)  # (k, size)
 
+    if live_t is None:
+        live_t = (None,) * len(bucket_shapes)
     interims = []
-    for (g, m), rows, vgs in zip(bucket_shapes, rows_t, vgs_t):
+    for (g, m), rows, vgs, live in zip(bucket_shapes, rows_t, vgs_t,
+                                       live_t):
         masked = _mask_rows(qs[rows], round_seed, vgs, g, m, secure_cfg)
         with jax.named_scope("vg_sum"):
-            if alive is not None:
-                masked = jnp.where(alive[rows][:, None], masked,
+            if live is not None:
+                masked = jnp.where(live[:, None], masked,
                                    jnp.zeros((), U32))
             interims.append(masking.vg_sums(masked, g))     # (m, size)
     return jnp.concatenate(interims, axis=0)                # (G, size)
@@ -191,8 +198,8 @@ def _cohort_interims(flat, round_seed, key, rows_t, vgs_t, *,
     permutation (``rows_t`` row indices, ``vgs_t`` group ids) is traced,
     so rounds with the same cohort/bucket geometry hit the jit cache even
     though ``make_virtual_groups`` reshuffles clients every round."""
-    stacked = _interims_body(flat, round_seed, key, rows_t, vgs_t, None,
-                             bucket_shapes, secure_cfg, dp_cfg)
+    stacked = _interims_body(flat, None, round_seed, key, rows_t, vgs_t,
+                             None, bucket_shapes, secure_cfg, dp_cfg)
     # pod-shard axis: fold each disjoint VG shard into its limb state
     # INSIDE this jit (tier 1, exact); the cross-shard merge + float tail
     # run in the shared executables outside (aggregate_flat).
@@ -201,15 +208,17 @@ def _cohort_interims(flat, round_seed, key, rows_t, vgs_t, *,
 
 @partial(jax.jit,
          static_argnames=("bucket_shapes", "secure_cfg", "dp_cfg"))
-def _cohort_interims_churn(flat, round_seed, key, rows_t, vgs_t, alive, *,
-                           bucket_shapes, secure_cfg, dp_cfg):
+def _cohort_interims_churn(flat, row_ids, round_seed, key, rows_t, vgs_t,
+                           live_t, *, bucket_shapes, secure_cfg, dp_cfg):
     """Churn twin of :func:`_cohort_interims`: survivor-only group sums
     returned RAW (G, size) — mask recovery scatter-adds onto them before
-    the limb fold, so the fold runs outside this jit. ``alive`` is a
-    traced row mask; rounds that only differ in WHO dropped reuse the
-    executable."""
-    return _interims_body(flat, round_seed, key, rows_t, vgs_t, alive,
-                          bucket_shapes, secure_cfg, dp_cfg)
+    the limb fold, so the fold runs outside this jit. ``flat`` holds the
+    survivors' rows where they are (k of them), ``row_ids`` their cohort
+    rows, ``rows_t`` each group slot's row of ``flat`` and ``live_t`` its
+    survivor mask (see :func:`_interims_body`); all traced, so rounds
+    that only differ in WHO dropped reuse the executable."""
+    return _interims_body(flat, row_ids, round_seed, key, rows_t, vgs_t,
+                          live_t, bucket_shapes, secure_cfg, dp_cfg)
 
 
 @partial(jax.jit, static_argnames=("g", "secure_cfg", "dp_cfg"))
@@ -338,13 +347,26 @@ def aggregate_flat(flat, plan, client_order, round_seed, *,
 
 
 def _aggregate_flat(sp, flat, plan, client_order, round_seed, secure_cfg,
-                    dp_cfg, key, n_shards, alive, stats):
+                    dp_cfg, key, n_shards, alive, stats, positions=None):
     """:func:`aggregate_flat` inside its open ``secure_agg`` span ``sp``,
-    which it tags with the stage-2 route."""
-    with tracing.span("vg_plan", n=flat.shape[0]):
+    which it tags with the stage-2 route.
+
+    ``positions``: the churn route's row map — the (k,) cohort row of
+    each of ``flat``'s k rows, which then need not cover the cohort (the
+    survivors' rows where they are). None: row ``j`` is cohort row ``j``."""
+    with tracing.span("vg_plan", n=len(client_order)):
         buckets = plan_buckets(plan, client_order)
         n_shards = _check_plan(buckets, secure_cfg, n_shards)
-        rows_t = tuple(jnp.asarray(b.rows, jnp.int32) for b in buckets)
+        if positions is None:
+            rows_t = tuple(jnp.asarray(b.rows, jnp.int32) for b in buckets)
+        else:
+            # each group slot's row of ``flat``: the row map composed with
+            # the plan's rows; a dropped member's slot reads row 0 (any
+            # survivor), zeroed after masking by its slot's survivor mask
+            row_of = np.zeros(len(client_order), np.int32)
+            row_of[positions] = np.arange(len(positions))
+            rows_t = tuple(jnp.asarray(row_of[list(b.rows)])
+                           for b in buckets)
         vgs_t = tuple(jnp.asarray(b.vg_ids, U32) for b in buckets)
     bucket_shapes = tuple((b.g, b.n_groups) for b in buckets)
     n = flat.shape[0]
@@ -382,9 +404,12 @@ def _aggregate_flat(sp, flat, plan, client_order, round_seed, secure_cfg,
 
     from repro.core import dropout
     alive = np.asarray(alive, bool)
-    if alive.shape[0] != n:
-        raise ValueError(f"alive mask has {alive.shape[0]} rows for "
-                         f"{n} clients")
+    n = len(client_order)
+    if alive.shape[0] != n or (positions is None and flat.shape[0] != n):
+        raise ValueError(f"alive mask has {alive.shape[0]} rows and flat "
+                         f"{flat.shape[0]} for {n} clients")
+    if positions is None:
+        positions = np.arange(n)
     if not alive.any():
         raise AggregationRefused(
             "no survivors: every selected client dropped — nothing to "
@@ -418,9 +443,11 @@ def _aggregate_flat(sp, flat, plan, client_order, round_seed, secure_cfg,
     with tracing.span("cohort_interims", n=n, churn=True) as isp:
         isp.transfer(h2d=flat)
         interims = _cohort_interims_churn(
-            jnp.asarray(flat), round_seed, key, rows_t, vgs_t,
-            jnp.asarray(alive), bucket_shapes=bucket_shapes,
-            secure_cfg=secure_cfg, dp_cfg=dp_cfg)
+            jnp.asarray(flat), jnp.asarray(positions, U32), round_seed, key,
+            rows_t, vgs_t, tuple(jnp.asarray(alive[list(b.rows)])
+                                 for b in buckets),
+            bucket_shapes=bucket_shapes, secure_cfg=secure_cfg,
+            dp_cfg=dp_cfg)
     with tracing.span("mask_recovery", n_dropped=n - n_survivors):
         interims = dropout.recover_interims(interims, buckets, alive,
                                             round_seed, stats=stats)
@@ -439,34 +466,32 @@ def aggregate_stacked(stacked_updates, plan, client_order, round_seed, *,
 
     ``cohort_order``: the churn path — the FULL selected cohort in
     protocol (row) order, a superset of ``client_order`` (the survivors
-    whose rows ``stacked_updates`` holds). Survivor rows scatter into
-    their full-cohort positions (zeros at dropped rows, which the alive
-    mask excludes), so each survivor keeps the DP key-fold of its
-    selection-time row and the recovered mean is bit-identical to a clean
-    round over the survivors."""
+    whose rows ``stacked_updates`` holds). The survivors' rows enter the
+    chain where they are, with a row map to their full-cohort positions
+    (no cohort-sized array is built), so each survivor keeps the DP
+    key-fold of its selection-time row and the recovered mean is
+    bit-identical to a clean round over the survivors."""
     template = jax.tree.map(lambda a: a[0], stacked_updates)
     _, unflatten = raveling.cached_unflatten(template)
     with tracing.span("secure_agg", n=len(client_order)) as sp:
         with tracing.span("ravel_rows") as rsp:
             rsp.transfer(h2d=stacked_updates)
             flat = ravel_rows(stacked_updates)
-        alive = None
+        alive = positions = None
         if cohort_order is not None \
                 and list(cohort_order) != list(client_order):
-            with tracing.span("scatter_survivors", n=len(client_order)):
+            with tracing.span("scatter_survivors", n=len(client_order),
+                              mapped_rows=len(client_order)):
                 cohort_order = list(cohort_order)
                 pos_of = {cid: j for j, cid in enumerate(cohort_order)}
-                positions = jnp.asarray([pos_of[c] for c in client_order],
-                                        jnp.int32)
-                full = jnp.zeros((len(cohort_order), flat.shape[1]),
-                                 flat.dtype)
-                flat = full.at[positions].set(flat)
+                positions = np.asarray([pos_of[c] for c in client_order],
+                                       np.int64)
                 alive = np.zeros(len(cohort_order), bool)
-                alive[np.asarray(positions)] = True
+                alive[positions] = True
                 client_order = cohort_order
         mean_flat = _aggregate_flat(sp, flat, plan, client_order,
                                     round_seed, secure_cfg, dp_cfg, key,
-                                    None, alive, stats)
+                                    None, alive, stats, positions)
     return unflatten(mean_flat)
 
 

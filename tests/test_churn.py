@@ -405,6 +405,120 @@ class TestChurnRounds:
 
 
 # ---------------------------------------------------------------------------
+# the stacked churn route: survivors' rows enter the chain in place
+# ---------------------------------------------------------------------------
+
+def _stacked_churn_case(n, vg, drop, seed=0):
+    """-> (cohort, survivors, plan, dropped, survivor-only stacked pytree,
+    the zero-filled full-cohort (n, size) rows)."""
+    rng = np.random.RandomState(seed + n)
+    cohort = [f"c{i:03d}" for i in range(n)]
+    plan = make_virtual_groups(cohort, vg, seed=seed + 2)
+    if drop == "void":
+        # leave one survivor in the first group (voided at a threshold of
+        # two) and drop the last cohort row besides
+        grp = plan.groups[0].members
+        dropped = set(grp[1:]) | {cohort[-1]}
+    else:
+        dropped = {cohort[j] for j in drop}
+    survivors = [c for c in cohort if c not in dropped]
+    rows = {c: (rng.uniform(-1.2, 1.2, 15).astype(np.float32),
+                rng.uniform(-1.2, 1.2, 8).astype(np.float32))
+            for c in cohort}
+    stacked = {"a": jnp.asarray(np.stack([rows[c][0] for c in survivors])),
+               "b": jnp.asarray(np.stack([rows[c][1] for c in survivors]))}
+    full = np.stack([np.concatenate(rows[c]) if c not in dropped
+                     else np.zeros(23, np.float32) for c in cohort])
+    return cohort, survivors, plan, dropped, stacked, jnp.asarray(full)
+
+
+class TestStackedChurnInPlace:
+    @pytest.mark.parametrize("n,vg,mech,noise,drop,min_surv", [
+        (12, 4, "off", 0.0, (0, 11), 1),             # first + last row
+        (12, 4, "local", 0.6, (0, 11), 1),
+        (12, 4, "global", 0.5, (0, 11), 1),
+        (13, 4, "off", 0.0, (0, 6, 12), 1),          # two bucket sizes
+        (13, 4, "local", 0.8, (0, 6, 12), 1),
+        (13, 4, "global", 0.5, (1, 12), 1),
+        (12, 4, "local", 0.4, "void", 2),            # a group voided
+        (13, 4, "global", 0.5, "void", 2),
+    ])
+    def test_matches_zero_filled_cohort_bit_for_bit(self, n, vg, mech,
+                                                    noise, drop, min_surv):
+        """Survivor-only stacked rows through ``cohort_order`` equal the
+        zero-filled full-cohort rows through ``aggregate_flat(alive=…)``
+        to the bit, under no, local and global DP."""
+        cohort, survivors, plan, dropped, stacked, full = \
+            _stacked_churn_case(n, vg, drop)
+        seed = jnp.asarray([5, 8], jnp.uint32)
+        key = jax.random.PRNGKey(n)
+        scfg = sa.SecureAggConfig(bits=20, min_survivors_per_vg=min_surv)
+        dcfg = dp_mod.DPConfig(mechanism=mech, clip_norm=0.5,
+                               noise_multiplier=noise)
+        alive = np.asarray([c not in dropped for c in cohort])
+        ref_stats, stats = {}, {}
+        ref = pe.aggregate_flat(full, plan, cohort, seed, secure_cfg=scfg,
+                                dp_cfg=dcfg, key=key, alive=alive,
+                                stats=ref_stats)
+        out = pe.aggregate_stacked(stacked, plan, survivors, seed,
+                                   secure_cfg=scfg, dp_cfg=dcfg, key=key,
+                                   cohort_order=cohort, stats=stats)
+        got = np.concatenate([np.asarray(out["a"]), np.asarray(out["b"])])
+        np.testing.assert_array_equal(got, np.asarray(ref))
+        assert stats["n_dropped"] == ref_stats["n_dropped"]
+        assert stats["n_voided_groups"] == ref_stats["n_voided_groups"] \
+            == (1 if drop == "void" else 0)
+        assert len({b.g for b in pe.plan_buckets(plan, cohort)}) == \
+            (2 if n == 13 else 1)
+
+    def test_churn_program_gets_survivor_rows_only(self, monkeypatch):
+        cohort, survivors, plan, _, stacked, _ = \
+            _stacked_churn_case(12, 4, (3, 7, 8))
+        seen = []
+        real = pe._cohort_interims_churn
+
+        def record(flat, row_ids, *args, **kw):
+            seen.append((flat.shape, row_ids.shape))
+            return real(flat, row_ids, *args, **kw)
+
+        monkeypatch.setattr(pe, "_cohort_interims_churn", record)
+        pe.aggregate_stacked(stacked, plan, survivors,
+                             jnp.asarray([1, 2], jnp.uint32),
+                             cohort_order=cohort)
+        assert seen == [((9, 23), (9,))]
+
+    def test_same_survivor_count_reuses_the_executable(self):
+        """Rounds that differ only in who dropped (and in the plan's
+        shuffle) compile the churn program once."""
+        size = 37                       # a shape no other test compiles
+        cohort = [f"c{i:03d}" for i in range(12)]
+        rng = np.random.RandomState(3)
+        before = pe._cohort_interims_churn._cache_size()
+        for r, drop in enumerate([(0, 5), (4, 11)]):
+            survivors = [c for j, c in enumerate(cohort) if j not in drop]
+            stacked = {"w": jnp.asarray(rng.uniform(
+                -1, 1, (len(survivors), size)).astype(np.float32))}
+            plan = make_virtual_groups(cohort, 4, seed=r)
+            pe.aggregate_stacked(stacked, plan, survivors,
+                                 jnp.asarray([r, 1], jnp.uint32),
+                                 cohort_order=cohort)
+            assert pe._cohort_interims_churn._cache_size() == before + 1
+
+    def test_scatter_survivors_span_counts_mapped_rows(self):
+        from repro import tracing
+        cohort, survivors, plan, _, stacked, _ = \
+            _stacked_churn_case(12, 4, (2, 9))
+        with tracing.use_tracer(tracing.Tracer()) as tr:
+            pe.aggregate_stacked(stacked, plan, survivors,
+                                 jnp.asarray([3, 1], jnp.uint32),
+                                 cohort_order=cohort)
+        (root,) = tr.find_roots("secure_agg")
+        (sp,) = [c for c in root.children if c.name == "scatter_survivors"]
+        assert sp.attrs["mapped_rows"] == len(survivors) == 10
+        assert "h2d_bytes" not in sp.attrs
+
+
+# ---------------------------------------------------------------------------
 # service layer + simulator
 # ---------------------------------------------------------------------------
 
